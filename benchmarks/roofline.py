@@ -5,10 +5,11 @@ Per (arch × shape × mesh):
     memory term     = HBM bytes         / (chips × HBM bytes/s)
     collective term = collective bytes  / (chips × ICI bytes/s per link)
 
-The machine constants come from :mod:`repro.kernels.hw_model` — the same
-``HardwareModel`` the kernel-variant cost model prices Pallas block
-configurations with, so a kernel the selector calls compute-bound can
-never look memory-bound in this table.
+The machine constants are the published TPU v5e row of
+:mod:`repro.kernels.hw_model` — the same ``HardwareModel`` the
+kernel-variant cost model prices Pallas block configurations with, so a
+kernel the selector calls compute-bound can never look memory-bound in
+this table.  They are the chip's datasheet peaks, not measurements.
 
 Two data sources, auto-selected:
 
@@ -30,11 +31,11 @@ import os
 from typing import Dict, List, Optional
 
 from repro.configs import ARCHS, SHAPES, get_config
-from repro.kernels.hw_model import DEFAULT_HW
+from repro.kernels.hw_model import TPU_V5E
 
-PEAK_FLOPS = DEFAULT_HW.peak_flops   # bf16 / chip
-HBM_BW = DEFAULT_HW.hbm_bw           # bytes/s / chip
-LINK_BW = DEFAULT_HW.link_bw         # bytes/s / link (ICI)
+PEAK_FLOPS = TPU_V5E.peak_flops   # bf16 / chip
+HBM_BW = TPU_V5E.hbm_bw           # bytes/s / chip
+LINK_BW = TPU_V5E.link_bw         # bytes/s / link (ICI)
 
 _BYTES_PER_PARAM = 2                 # bf16 weights
 _ANALYTIC_CHIPS = 256
@@ -113,7 +114,7 @@ def analyze_record(rec: Dict) -> Optional[Dict]:
     row.update(
         arch=rec["arch"], shape=rec["shape"], mesh=rec["mesh"],
         mem_per_device_gib=mem.get("total_per_device_bytes", 0) / 2**30,
-        fits_hbm=mem.get("total_per_device_bytes", 0) <= 16 * 2**30)
+        fits_hbm=mem.get("total_per_device_bytes", 0) <= TPU_V5E.hbm_bytes)
     return row
 
 
@@ -129,7 +130,7 @@ def analytic_record(arch: str, shape_name: str,
     per_dev = (cfg.param_count(active_only=False) * _BYTES_PER_PARAM) / chips
     row.update(arch=arch, shape=shape_name, mesh=f"analytic/{chips}",
                mem_per_device_gib=per_dev / 2**30,
-               fits_hbm=per_dev <= 16 * 2**30)
+               fits_hbm=per_dev <= TPU_V5E.hbm_bytes)
     return row
 
 

@@ -6,27 +6,20 @@ terms/factors we walk structurally (``_sorted_terms`` → ``(_DimTerm, coeff)``;
 variable or an operation (floordiv/mod/max/min) over sub-_DimExprs).
 
 This module is the bridge between the tracing frontend (jaxprs with
-polymorphic avals) and the paper's symbolic machinery.  If JAX internals
-shift, ``dim_to_expr`` falls back to parsing nothing — it raises, and the
-caller treats the dim as a fresh opaque symbol, which is sound (it only
-reduces comparability, never correctness).
+polymorphic avals) and the paper's symbolic machinery.  It is written
+against the JAX release pinned in ``pyproject.toml``.
 """
 from __future__ import annotations
 
 from typing import Any, Mapping, Optional, Tuple
 
-from .expr import Atom, OpAtom, SymbolicExpr
+from jax._src.export.shape_poly import _DimExpr
 
-try:  # JAX >= 0.4.30 layout
-    from jax._src.export import shape_poly as _sp
-
-    _DimExpr = _sp._DimExpr
-except Exception:  # pragma: no cover - environment without jax.export internals
-    _DimExpr = ()
+from .expr import SymbolicExpr
 
 
 def is_symbolic_dim(d: Any) -> bool:
-    return isinstance(d, _DimExpr) if _DimExpr else False
+    return isinstance(d, _DimExpr)
 
 
 def dim_to_expr(d: Any) -> SymbolicExpr:

@@ -24,6 +24,7 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 NEG_INF = -1e30
 
@@ -118,19 +119,10 @@ def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
         out_specs=pl.BlockSpec((1, block_q, hd), lambda bh, qi, ki: (bh, qi, 0)),
         out_shape=jax.ShapeDtypeStruct((b * hq, s_pad, hd), q.dtype),
         scratch_shapes=[
-            _scratch((block_q, 1)),    # m (running max)
-            _scratch((block_q, 1)),    # l (running denominator)
-            _scratch((block_q, hd)),   # acc (weighted values)
+            pltpu.VMEM((block_q, 1), jnp.float32),    # m (running max)
+            pltpu.VMEM((block_q, 1), jnp.float32),    # l (running denominator)
+            pltpu.VMEM((block_q, hd), jnp.float32),   # acc (weighted values)
         ],
         interpret=interpret,
     )(qf, kf, vf)
     return out.reshape(b, hq, s_pad, hd)[:, :, :s]
-
-
-def _scratch(shape):
-    from jax.experimental import pallas as pl
-    try:  # TPU memory space when available, plain VMEM otherwise
-        from jax.experimental.pallas import tpu as pltpu
-        return pltpu.VMEM(shape, jnp.float32)
-    except Exception:  # pragma: no cover
-        return pl.VMEM(shape, jnp.float32)

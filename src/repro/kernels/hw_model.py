@@ -4,40 +4,51 @@
 ``kernels/variants.py`` (the per-kernel analytical cost model) must agree
 on what the machine can do — peak FLOP rate, HBM bandwidth, VMEM
 capacity, MXU/VPU geometry — or a kernel the cost model calls
-compute-bound would look memory-bound in the roofline table.  Both import
+compute-bound would look memory-bound in the roofline table.  Both read
 from here; nothing else in the repo hard-codes a TFLOP/s.
 
-The defaults describe a TPU v5e-class chip (the target the Pallas
-kernels are tiled for):
+Rows are keyed by the ``device_kind`` JAX reports, and each names its
+source.  :func:`hardware_for` picks the row of the device in use: a TPU
+whose kind has no row raises rather than borrowing another chip's
+numbers.  On the CPU the Pallas kernels run in interpret mode as a
+stand-in for the TPU they are tiled for, so selection prices them with
+the v5e row, by name — those are the v5e's published numbers, never a
+measurement of the CPU.
+
+A row describes a chip:
 
 * one MXU of 128x128 ALUs — matmul operands want every contracting /
   non-contracting tile dimension at (a multiple of) 128;
 * a VPU of (8, 128) lanes for elementwise work;
-* ~16 MiB of VMEM per core, shared by every in-flight block and the
-  pipeline's double buffers — the cost model's *validity* constraint;
+* the scoped VMEM a kernel may use, shared by every in-flight block and
+  the pipeline's double buffers — the cost model's *validity* constraint;
 * per-``pallas_call`` launch overhead, the constant that makes the
-  reference implementation win for degenerate shapes.
+  reference implementation win for degenerate shapes (the launch,
+  dispatch and grid-step constants are modelled, not measured).
 
-Values are per chip.  ``HardwareModel`` is a frozen dataclass so a test
-(or a different deployment target) can carry its own instance; module
-attributes ``PEAK_FLOPS`` / ``HBM_BW`` / ``LINK_BW`` keep the names the
-roofline benchmark has always exported.
+``HardwareModel`` is a frozen dataclass so a test can carry its own
+instance (``with_vmem``).
 """
 from __future__ import annotations
 
 from dataclasses import dataclass, replace
+from typing import Dict
+
+import jax
 
 
 @dataclass(frozen=True)
 class HardwareModel:
     """Per-chip machine constants consumed by cost model + roofline."""
 
-    name: str = "tpu-v5e"
-    peak_flops: float = 197e12       # bf16 MXU FLOP/s
+    kind: str                        # jax.Device.device_kind
+    source: str                      # where the published numbers come from
+    peak_flops: float                # bf16 MXU FLOP/s
+    hbm_bw: float                    # HBM bytes/s
+    hbm_bytes: int                   # HBM capacity
+    link_bw: float                   # ICI bytes/s per link
     vpu_flops: float = 12.3e12       # f32 elementwise FLOP/s (8x128 VPU)
-    hbm_bw: float = 819e9            # HBM bytes/s
-    link_bw: float = 50e9            # ICI bytes/s per link
-    vmem_bytes: int = 16 * 2**20     # usable VMEM per core
+    vmem_bytes: int = 16 * 2**20     # scoped VMEM a kernel may use
     mxu_dim: int = 128               # systolic array edge
     vpu_sublanes: int = 8            # VREG is (8, 128)
     vpu_lanes: int = 128
@@ -55,12 +66,38 @@ class HardwareModel:
         return replace(self, vmem_bytes=vmem_bytes)
 
 
-DEFAULT_HW = HardwareModel()
+TPU_V5E = HardwareModel(
+    kind="TPU v5 lite",
+    source=("Google Cloud documentation, 'TPU v5e': 197 TFLOP/s bf16, "
+            "16 GiB HBM at 819 GB/s, 1,600 Gbit/s ICI over 4 links"),
+    peak_flops=197e12,
+    hbm_bw=819e9,
+    hbm_bytes=16 * 2**30,
+    link_bw=50e9,
+)
 
-# legacy module-level names (roofline's original constants)
-PEAK_FLOPS = DEFAULT_HW.peak_flops
-HBM_BW = DEFAULT_HW.hbm_bw
-LINK_BW = DEFAULT_HW.link_bw
+HARDWARE: Dict[str, HardwareModel] = {TPU_V5E.kind: TPU_V5E}
+
+
+def hardware_for(device=None) -> HardwareModel:
+    """The row for ``device`` (default: JAX's first device).
+
+    CPU: the v5e row (interpret mode stands in for it).  TPU: the row of
+    its ``device_kind``; an unknown kind raises.  Any other platform
+    raises — the Pallas kernels target TPUs only."""
+    if device is None:
+        device = jax.devices()[0]
+    if device.platform == "cpu":
+        return TPU_V5E
+    if device.platform == "tpu":
+        try:
+            return HARDWARE[device.device_kind]
+        except KeyError:
+            raise ValueError(
+                f"no hardware row for device_kind {device.device_kind!r}; "
+                f"add one to repro.kernels.hw_model.HARDWARE with its "
+                f"source (known: {sorted(HARDWARE)})") from None
+    raise ValueError(f"no hardware model for platform {device.platform!r}")
 
 
 def mxu_efficiency(hw: HardwareModel, *tile_dims: int) -> float:

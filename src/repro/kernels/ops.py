@@ -20,9 +20,10 @@ Dispatch rules of the wrappers:
   call under ``repro.optimize`` tracing leaves the sentinel in the node
   params for plan-time per-bucket selection to overwrite.
 
-On a real TPU runtime ``interpret`` resolves to ``False``; this CPU
-container validates with ``interpret=True`` which executes the kernel
-body in Python.
+``interpret`` left unset resolves from the backend: ``False`` on a TPU
+(the compiled Mosaic kernel), ``True`` on the CPU (the kernel body runs
+in Python — how the test suite checks it).  Any other backend raises: a
+kernel never runs interpreted on a device that was not asked for.
 """
 from __future__ import annotations
 
@@ -33,6 +34,7 @@ import jax
 import jax.numpy as jnp
 
 from jax.extend.core import Primitive
+from jax.interpreters import mlir
 
 from repro.core.ir.dynamism import DimIntroSpec, register_introduces_dim
 
@@ -43,7 +45,14 @@ from . import variants as _variants
 
 
 def _default_interpret() -> bool:
-    return jax.default_backend() != "tpu"
+    backend = jax.default_backend()
+    if backend == "tpu":
+        return False
+    if backend == "cpu":
+        return True
+    raise RuntimeError(
+        f"the Pallas TPU kernels have no path on backend {backend!r}: run "
+        f"on a TPU, or on the CPU (interpret mode), or pass impl='ref'")
 
 
 # jit'd workers — every knob static so each resolved configuration
@@ -116,11 +125,8 @@ def _kernel_primitive(name: str, run) -> Primitive:
         return ShapedArray(a.shape, a.dtype)
 
     p.def_abstract_eval(abse)
-    try:  # usable under an outer jax.jit where available
-        from jax.interpreters import mlir
-        mlir.register_lowering(p, mlir.lower_fun(run, multiple_results=False))
-    except Exception:
-        pass
+    # usable under an outer jax.jit
+    mlir.register_lowering(p, mlir.lower_fun(run, multiple_results=False))
     return p
 
 

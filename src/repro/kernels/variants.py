@@ -43,7 +43,7 @@ from dataclasses import dataclass, field
 from typing import Any, Callable, Dict, List, Mapping, Optional, Sequence, \
     Tuple
 
-from .hw_model import DEFAULT_HW, HardwareModel, mxu_efficiency
+from .hw_model import HardwareModel, hardware_for, mxu_efficiency
 
 # dims the cost model probes when a range has no upper bound: a heuristic
 # *pricing* point only — validity never relies on it (unbounded Pallas
@@ -224,10 +224,11 @@ def rmsnorm_vmem_bytes(variant: KernelVariant, n_hi: Optional[int],
 
 def variant_vmem_bytes(prim_name: str, variant: KernelVariant,
                        hi_shape: Mapping[str, Optional[int]], itemsize: int,
-                       hw: HardwareModel = DEFAULT_HW) -> Optional[int]:
+                       hw: Optional[HardwareModel] = None) -> Optional[int]:
     """Worst-case footprint over a range's upper corner (``None`` dims =
     unbounded).  The validity predicate is ``footprint <= hw.vmem_bytes``
     with ``None`` meaning unboundable → invalid."""
+    hw = hw or hardware_for()
     if prim_name == "flash_attention":
         return flash_vmem_bytes(variant, hi_shape.get("s"), hi_shape.get("t"),
                                 hi_shape.get("hd"), itemsize, hw)
@@ -239,7 +240,8 @@ def variant_vmem_bytes(prim_name: str, variant: KernelVariant,
 
 def variant_valid(prim_name: str, variant: KernelVariant,
                   hi_shape: Mapping[str, Optional[int]], itemsize: int,
-                  hw: HardwareModel = DEFAULT_HW) -> bool:
+                  hw: Optional[HardwareModel] = None) -> bool:
+    hw = hw or hardware_for()
     vm = variant_vmem_bytes(prim_name, variant, hi_shape, itemsize, hw)
     return vm is not None and vm <= hw.vmem_bytes
 
@@ -261,7 +263,7 @@ def _causal_block_pairs(nq: int, nk: int, bq: int, bkv: int) -> int:
 
 def flash_cost(variant: KernelVariant, shape: Mapping[str, int],
                itemsize: int, params: Mapping[str, Any],
-               hw: HardwareModel = DEFAULT_HW) -> VariantCost:
+               hw: HardwareModel) -> VariantCost:
     """Price one flash-attention variant at one concrete shape."""
     b, hq = shape["b"], shape["hq"]
     s, t, hd = shape["s"], shape["t"], shape["hd"]
@@ -303,7 +305,7 @@ def flash_cost(variant: KernelVariant, shape: Mapping[str, int],
 
 def rmsnorm_cost(variant: KernelVariant, shape: Mapping[str, int],
                  itemsize: int, params: Mapping[str, Any],
-                 hw: HardwareModel = DEFAULT_HW) -> VariantCost:
+                 hw: HardwareModel) -> VariantCost:
     """Price one rmsnorm variant at one concrete shape (n rows × d)."""
     n, d = shape["n"], shape["d"]
     if variant.impl == "ref":
@@ -432,7 +434,7 @@ def _probe_shapes(bounds: Mapping[str, Tuple[int, Optional[int]]]
 def select_variant(prim_name: str,
                    bounds: Mapping[str, Tuple[int, Optional[int]]],
                    itemsize: int, params: Mapping[str, Any],
-                   hw: HardwareModel = DEFAULT_HW,
+                   hw: Optional[HardwareModel] = None,
                    forced: Optional[str] = None
                    ) -> Tuple[KernelVariant, Dict[str, float], List[Dict[str, int]], Tuple[str, ...]]:
     """Pick the cheapest VMEM-valid variant over one shape range.
@@ -442,6 +444,7 @@ def select_variant(prim_name: str,
     footprints are monotone in every dim); scores sum the model time over
     the lo/mid/hi pricing corners.  ``forced`` pins a variant by name
     (measured re-selection) — it must still be valid."""
+    hw = hw or hardware_for()
     entry = _REGISTRY[prim_name]
     hi_shape = {k: hi for k, (_lo, hi) in bounds.items()}
     probes = _probe_shapes(bounds)
@@ -478,7 +481,7 @@ def node_bounds(node, sg) -> Dict[str, Tuple[int, Optional[int]]]:
     return {k: _expr_bounds(e, sg) for k, e in exprs.items()}
 
 
-def select_for_node(node, sg, hw: HardwareModel = DEFAULT_HW,
+def select_for_node(node, sg, hw: Optional[HardwareModel] = None,
                     forced: Optional[str] = None) -> KernelSelection:
     """Select a variant for one kernel node under a plan's shape graph."""
     prim_name = node.prim_name
@@ -493,7 +496,7 @@ def select_for_node(node, sg, hw: HardwareModel = DEFAULT_HW,
                            invalid=invalid, measured=forced is not None)
 
 
-def select_kernels(graph, sg, hw: HardwareModel = DEFAULT_HW,
+def select_kernels(graph, sg, hw: Optional[HardwareModel] = None,
                    forced: Optional[Mapping[int, str]] = None,
                    decisions=None) -> Dict[int, KernelSelection]:
     """Select a variant for every registered kernel node in ``graph``.
@@ -526,7 +529,7 @@ def select_kernels(graph, sg, hw: HardwareModel = DEFAULT_HW,
 
 def select_eager(prim_name: str, shape: Mapping[str, int], itemsize: int,
                  params: Mapping[str, Any],
-                 hw: HardwareModel = DEFAULT_HW) -> KernelVariant:
+                 hw: Optional[HardwareModel] = None) -> KernelVariant:
     """Cost-model choice at one *concrete* shape (the eager-call path:
     ``kernels.rmsnorm(x, scale)`` with no explicit impl)."""
     bounds = {k: (int(v), int(v)) for k, v in shape.items()}
@@ -541,7 +544,7 @@ def select_eager(prim_name: str, shape: Mapping[str, int], itemsize: int,
 
 
 def measure_variants(prim_name: str, node, env: Mapping[str, int],
-                     hw: HardwareModel = DEFAULT_HW, repeats: int = 3
+                     hw: Optional[HardwareModel] = None, repeats: int = 3
                      ) -> Dict[str, float]:
     """Wall-time every VMEM-valid variant of ``node`` at ``env``.
 
@@ -549,6 +552,7 @@ def measure_variants(prim_name: str, node, env: Mapping[str, int],
     irrelevant to timing), runs each valid variant once to warm the jit
     cache, then takes the best of ``repeats`` timed calls.  Returns
     variant name -> seconds."""
+    hw = hw or hardware_for()
     import time as _time
 
     import jax

@@ -29,6 +29,7 @@ from ..data import DataPipeline, PipelineConfig
 from ..distributed import StragglerMonitor
 from ..models import init_params
 from ..optim import init_state
+from .compile_cache import configure_compile_cache
 from .steps import adamw_config_for, make_train_step
 
 
@@ -130,6 +131,7 @@ def main() -> None:
     ap.add_argument("--memory-limit-mb", type=int, default=0)
     ap.add_argument("--ckpt-dir", default=None)
     args = ap.parse_args()
+    configure_compile_cache()
     cfg = get_smoke_config(args.arch) if args.smoke else get_config(args.arch)
     stats = train(cfg, steps=args.steps, batch_size=args.batch_size,
                   mode=args.mode, data_mode=args.data_mode,

@@ -34,7 +34,7 @@ from hypothesis import strategies as st
 
 from repro.core import optimize, symbolic_dim, symbolic_dims
 from repro.kernels import flash_attention, masked_select, rmsnorm
-from repro.kernels.hw_model import DEFAULT_HW
+from repro.kernels.hw_model import TPU_V5E
 from repro.kernels.ref import reference_attention, reference_rmsnorm
 from repro.kernels.variants import (default_variant, node_bounds,
                                     registered_kernels, select_eager,
@@ -126,11 +126,11 @@ def test_valid_set_shrinks_with_vmem_budget(prim):
     implementation (footprint 0) survives every budget."""
     hi = ({"s": 4096, "t": 4096, "hd": 128} if prim == "flash_attention"
           else {"n": 1 << 16, "d": 4096})
-    budgets = [DEFAULT_HW.vmem_bytes, 4 << 20, 1 << 20, 256 << 10,
+    budgets = [TPU_V5E.vmem_bytes, 4 << 20, 1 << 20, 256 << 10,
                32 << 10, 1]
     prev = None
     for budget in budgets:
-        hw = DEFAULT_HW.with_vmem(budget)
+        hw = TPU_V5E.with_vmem(budget)
         valid = {v.name for v in variants_for(prim)
                  if variant_valid(prim, v, hi, 4, hw)}
         ref = {v.name for v in variants_for(prim) if v.impl == "ref"}

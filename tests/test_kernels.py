@@ -70,3 +70,15 @@ def test_rmsnorm_3d_input():
     r = reference_rmsnorm(x, s)
     assert o.shape == x.shape
     assert float(jnp.max(jnp.abs(o - r))) < 1e-4
+
+
+def test_interpret_mode_only_by_default_on_the_cpu(monkeypatch):
+    """Unset ``interpret`` compiles on a TPU, interprets on the CPU, and
+    refuses any other backend instead of silently interpreting there."""
+    from repro.kernels import ops
+    assert ops._default_interpret() is True
+    monkeypatch.setattr(ops.jax, "default_backend", lambda: "tpu")
+    assert ops._default_interpret() is False
+    monkeypatch.setattr(ops.jax, "default_backend", lambda: "gpu")
+    with pytest.raises(RuntimeError, match="no path on backend 'gpu'"):
+        ops._default_interpret()
