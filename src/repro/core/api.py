@@ -19,6 +19,7 @@ with LRU retention.
 """
 from __future__ import annotations
 
+import contextlib
 import threading
 import time
 from contextlib import contextmanager
@@ -28,6 +29,7 @@ from typing import Any, Callable, Dict, Iterable, List, Mapping, Optional, \
 
 import jax
 from jax import export, tree_util
+from jax.profiler import TraceAnnotation
 
 from .dispatch import BucketKey, BucketPlan, BucketSpace, BucketsSpec, \
     SpecializationTable, build_bucket_space
@@ -40,6 +42,7 @@ from .lowering import Program, lower_plan
 from .memplan import ArenaPlan, build_arena_plan
 from .memplan.arena import ArenaExhausted
 from .obs import NULL_TRACER, DecisionLog, Telemetry, Tracer
+from .obs.profile import profile_call
 from .remat.planner import ExecutionPlan, build_plan
 from .resilience import (BucketQuarantined, CircuitBreaker, CompileFault,
                          FaultPlan, FaultPlanRef, FaultSpec, OffloadFailure,
@@ -58,6 +61,14 @@ __all__ = [
 ]
 
 _EXECUTORS = ("vm", "reference")
+
+
+# a call is profiled (obs.profile) exactly while a jax.profiler session is
+# active; with none, a call pays this one check
+_profiling = TraceAnnotation.is_enabled
+# entered in place of a span outside a profiled call (reusable, allocates
+# nothing)
+_NO_SPAN = contextlib.nullcontext()
 
 
 def _build_executor(plan: ExecutionPlan, report: "OptimizeReport",
@@ -537,44 +548,53 @@ class DynamicShapeFunction:
         self._remeasure_threads: List[threading.Thread] = []
 
     def __call__(self, *args, **kwargs):
+        if _profiling():
+            return profile_call(self, args, kwargs)
+        return self._call(args, kwargs)
+
+    def _call(self, args: tuple, kwargs: dict, prof=None):
+        """Flatten, dispatch, unflatten.  ``prof`` is the call's
+        :class:`~repro.core.obs.profile.CallProfile` under a profiler
+        session (``None`` otherwise)."""
         flat, in_tree = tree_util.tree_flatten((args, kwargs))
         if in_tree != self._in_tree:
             raise TypeError(
                 f"pytree structure mismatch: traced {self._in_tree}, got {in_tree}")
         res = self._resilience
         if res is not None:
-            outs = self._call_resilient(res, flat)
+            outs = self._call_resilient(res, flat, prof)
         else:
-            outs = self._dispatch(flat)
+            outs = self._dispatch(flat, prof=prof)
         return tree_util.tree_unflatten(self._out_tree, outs)
 
     def _dispatch(self, flat: List[Any], *, force_fallback: bool = False,
-                  faults=None) -> List[Any]:
+                  faults=None, prof=None) -> List[Any]:
         """Select a plan and execute once (one ladder attempt).
 
         ``force_fallback=True`` serves the whole-range plan regardless of
         bucketing — the degradation ladder's remat-heavier retry rung,
         bitwise-identical to the bucket plans.  ``faults`` is an armed
         :class:`~repro.core.resilience.CallFaults` probe threaded down to
-        the executor (``None`` keeps every hot loop uninstrumented)."""
-        if self._table is None or force_fallback:
-            if self._table is not None:
-                env = solve_env(self.plan.graph, flat)
-                self._check_declared(env)
-                self.last_bucket = self._table.key_of(env)
-                self.last_arena_bound = self.report.arena_bound_bytes
-                outs, report = self.interp.run(flat, env=env, faults=faults)
-            else:
-                self.last_bucket = None
-                self.last_arena_bound = self.report.arena_bound_bytes
-                outs, report = self.interp.run(flat, faults=faults)
-            prog = self._program
-        else:
-            t0 = time.perf_counter_ns()
+        the executor (``None`` keeps every hot loop uninstrumented).
+
+        The env is solved and validated once, here, on every path; the
+        executor trusts it.  ``last_dispatch_ns`` is this call's solve,
+        range check, plan lookup (a miss includes the bucket's
+        specialization compile) and resolve."""
+        t0 = time.perf_counter_ns()
+        with (_NO_SPAN if prof is None else TraceAnnotation("dsf.solve_env")):
             env = solve_env(self.plan.graph, flat)
             self._check_declared(env)
+        if prof is not None:
+            prof.solve_ns += time.perf_counter_ns() - t0
+        bucketed = self._table is not None and not force_fallback
+        if not bucketed:
+            self.last_bucket = (None if self._table is None
+                                else self._table.key_of(env))
+            self.last_arena_bound = self.report.arena_bound_bytes
+            interp, prog = self.interp, self._program
+        else:
             bp, _hit = self._table.lookup(env)
-            dispatch_ns = time.perf_counter_ns() - t0
             # bp.key is None when a background miss served the whole-range
             # fallback; re-derive the bucket from this request's own env
             # (shared table state could have moved under concurrent traffic).
@@ -583,26 +603,29 @@ class DynamicShapeFunction:
             self.last_bucket = bp.key if bp.key is not None \
                 else self._table.key_of(env)
             self.last_arena_bound = bp.report.arena_bound_bytes
-            # env is solved + validated once, here; the interpreter trusts
-            # it.  The began/ended bracket tells the background worker a
-            # request is mid-flight so compiles defer instead of contending
-            # (skipped without a worker: it is two lock round-trips per call)
-            if self._table.background:
-                self._table.request_began()
-                try:
-                    outs, report = bp.interp.run(flat, env=env, faults=faults)
-                finally:
-                    self._table.request_ended()
-            else:
-                outs, report = bp.interp.run(flat, env=env, faults=faults)
-            st = report.stats
-            st.last_dispatch_ns = dispatch_ns
-            with self._stats_lock:
-                self._dispatch_ns_total += dispatch_ns
-                st.dispatch_ns_total = self._dispatch_ns_total
+            interp, prog = bp.interp, bp.program
+        dispatch_ns = time.perf_counter_ns() - t0
+        # The began/ended bracket tells the background worker a request
+        # is mid-flight so compiles defer instead of contending (skipped
+        # without a worker: it is two lock round-trips per call)
+        if bucketed and self._table.background:
+            self._table.request_began()
+            try:
+                outs, report = interp.run(flat, env=env, faults=faults,
+                                          prof=prof)
+            finally:
+                self._table.request_ended()
+        else:
+            outs, report = interp.run(flat, env=env, faults=faults,
+                                      prof=prof)
+        st = report.stats
+        st.last_dispatch_ns += dispatch_ns
+        with self._stats_lock:
+            self._dispatch_ns_total += st.last_dispatch_ns
+            st.dispatch_ns_total = self._dispatch_ns_total
+        if bucketed:
             st.bucket_hits = self._table.hits
             st.specialize_count = self._table.specialize_count
-            prog = bp.program
             if self._kernel_remeasure_after is not None and \
                     self.last_bucket is not None:
                 self._maybe_remeasure(self.last_bucket, env)
@@ -610,10 +633,12 @@ class DynamicShapeFunction:
         tel = self._telemetry
         if tel is not None:
             self._record_call(tel, report, prog)
+        if prof is not None:
+            prof.report, prof.program = report, prog
         return outs
 
     def _call_resilient(self, res: ResilienceController,
-                        flat: List[Any]) -> List[Any]:
+                        flat: List[Any], prof=None) -> List[Any]:
         """Degradation-ladder dispatch (resilience enabled).
 
         Rungs, in order: the plain dispatch (whose executor already runs
@@ -644,7 +669,7 @@ class DynamicShapeFunction:
         while True:
             try:
                 return self._dispatch(flat, force_fallback=force_fb,
-                                      faults=armed)
+                                      faults=armed, prof=prof)
             except (TransientKernelError, RegenFailure,
                     OffloadFailure) as e:
                 err, rung, fb_next = e, "retry-transient", force_fb
@@ -680,14 +705,15 @@ class DynamicShapeFunction:
             armed = fp.arm_call(seq) if fp is not None else None
 
     def _record_call(self, tel: Telemetry, report: RunReport,
-                     program: Optional[Program]) -> None:
-        """Telemetry-enabled path only (never reached when disabled)."""
+                     program: Optional[Program], profile=None) -> None:
+        """Telemetry-enabled or profiled calls only (never reached on the
+        disabled path)."""
         trips: Tuple[int, ...] = ()
         if program is not None and program.loops:
             trips = tuple(rl.trip
                           for rl in program.resolve(report.env).loops)
         key = self.last_bucket if self._table is not None else None
-        tel.on_call(key, report, program=program, loop_trips=trips)
+        tel.on_call(key, report, loop_trips=trips, profile=profile)
 
     def _check_declared(self, env: Dict[str, int]) -> None:
         """Declared-range contract check against the *whole-range* graph —
@@ -724,16 +750,11 @@ class DynamicShapeFunction:
                 "Program under the reference interpreter)")
         return diff_timeline(prog, env)
 
-    def enable_telemetry(self, capacity: int = 256,
-                         sample_timeline_every: int = 0) -> Telemetry:
+    def enable_telemetry(self, capacity: int = 256) -> Telemetry:
         """Attach a per-call telemetry ring (see
-        :class:`repro.core.obs.Telemetry`).  ``sample_timeline_every=N``
-        additionally reconstructs the exact per-instruction memory
-        timeline of every N-th call (off the hot path, VM executor only).
-        Returns the live aggregate; read it any time, detach with
-        :meth:`disable_telemetry`."""
-        self._telemetry = Telemetry(
-            capacity=capacity, sample_timeline_every=sample_timeline_every)
+        :class:`repro.core.obs.Telemetry`).  Returns the live aggregate;
+        read it any time, detach with :meth:`disable_telemetry`."""
+        self._telemetry = Telemetry(capacity=capacity)
         return self._telemetry
 
     def disable_telemetry(self) -> Optional[Telemetry]:

@@ -98,7 +98,10 @@ class PlanInterpreter:
     # ---------------------------------------------------------------- run --
     def run(self, flat_args: Sequence[Any],
             env: Optional[Dict[str, int]] = None,
-            faults: Any = None) -> Tuple[List[Any], RunReport]:
+            faults: Any = None, prof: Any = None
+            ) -> Tuple[List[Any], RunReport]:
+        # ``prof``: accepted for the executor interface; the reference
+        # interpreter records no spans
         t0 = time.perf_counter()
         g, plan = self.g, self.plan
         if env is None:

@@ -21,12 +21,14 @@ The default executor.  Two regimes, chosen per dim binding by
 """
 from __future__ import annotations
 
+import contextlib
 import time
 from typing import Any, Dict, List, Optional, Sequence, Tuple
 
 import jax.numpy as jnp
 import numpy as np
 from jax import lax
+from jax.profiler import TraceAnnotation
 
 from ..ir.trace import solve_checked_env
 from ..lowering.program import (OP_BIND_ARG, OP_BIND_DIM, OP_COMPUTE,
@@ -37,6 +39,10 @@ from ..memplan.arena import ArenaAllocator, ArenaExhausted
 from ..remat.runtime import RuntimeRematPolicy
 from .interpreter import RunReport
 from .memory import MemoryManager, MemoryStats
+
+# entered in place of a span when no profiler session is active (reusable,
+# allocates nothing)
+_NO_SPAN = contextlib.nullcontext()
 
 
 class ProgramVM:
@@ -79,14 +85,23 @@ class ProgramVM:
     # ---------------------------------------------------------------- run --
     def run(self, flat_args: Sequence[Any],
             env: Optional[Dict[str, int]] = None,
-            faults: Any = None) -> Tuple[List[Any], RunReport]:
-        t0 = time.perf_counter()
+            faults: Any = None, prof: Any = None
+            ) -> Tuple[List[Any], RunReport]:
+        """Execute once.  ``prof`` is the call's
+        :class:`~repro.core.obs.profile.CallProfile` when a profiler
+        session is active (``None`` otherwise): the resolve and the stream
+        then run inside ``vm.resolve`` / ``vm.stream`` spans, timed."""
+        t0 = time.perf_counter_ns()
         prog = self.program
         if env is None:
             # pre-solved envs (bucketed dispatch hot path) skip both steps
             env = solve_checked_env(prog.graph, prog.plan.shape_graph,
                                     flat_args)
-        resolved = prog.resolve(env, self._size_cache, self._params_cache)
+        t1 = time.perf_counter_ns()
+        with (_NO_SPAN if prof is None else TraceAnnotation("vm.resolve")):
+            resolved = prog.resolve(env, self._size_cache,
+                                    self._params_cache)
+        t2 = time.perf_counter_ns()
         cap = self.arena_hard_cap
         if cap is not None and resolved.arena is not None \
                 and resolved.arena.arena_bytes > cap:
@@ -95,24 +110,36 @@ class ProgramVM:
             raise ArenaExhausted(
                 f"resolved arena reserve {resolved.arena.arena_bytes} "
                 f"exceeds the enforced bound of {cap} bytes")
-        if faults is None:
-            if resolved.fast_ok:
-                outs, stats = self._run_fast(flat_args, resolved)
-            else:
-                outs, stats = self._run_dynamic(flat_args, resolved, env)
-        elif resolved.fast_ok and not faults.needs_memory:
-            outs, stats = self._run_fast_faulted(flat_args, resolved, faults)
+        if prof is None:
+            outs, stats = self._execute(flat_args, resolved, env, faults)
         else:
-            # a memory-kind fault needs the allocation stream: the dynamic
-            # regime runs the full instruction list (bitwise-identical
-            # outputs — it is the generic path the fast stream specializes)
-            outs, stats = self._run_dynamic(flat_args, resolved, env,
-                                            faults=faults)
+            with TraceAnnotation("vm.stream"):
+                outs, stats = self._execute(flat_args, resolved, env, faults,
+                                            prof)
+            prof.resolve_ns += t2 - t1
+            prof.stream_ns += time.perf_counter_ns() - t2
+        # the VM's share of this call's dispatch overhead (env solve when
+        # not given, resolve); the caller adds its own solve and lookup
+        stats.last_dispatch_ns = t2 - t0
         if stats.measured_dims:
             # surface the measured (not cap) bound dims in the report env
             env = {**resolved.env, **stats.measured_dims}
-        wall = time.perf_counter() - t0
+        wall = (time.perf_counter_ns() - t0) * 1e-9
         return outs, RunReport(stats=stats, wall_s=wall, env=env)
+
+    def _execute(self, flat_args: Sequence[Any], resolved: ResolvedProgram,
+                 env: Dict[str, int], faults: Any, prof: Any = None
+                 ) -> Tuple[List[Any], MemoryStats]:
+        if faults is None:
+            if resolved.fast_ok:
+                return self._run_fast(flat_args, resolved, prof)
+            return self._run_dynamic(flat_args, resolved, env)
+        if resolved.fast_ok and not faults.needs_memory:
+            return self._run_fast_faulted(flat_args, resolved, faults)
+        # a memory-kind fault needs the allocation stream: the dynamic
+        # regime runs the full instruction list (bitwise-identical outputs
+        # — it is the generic path the fast stream specializes)
+        return self._run_dynamic(flat_args, resolved, env, faults=faults)
 
     # ------------------------------------------------------------- loops --
     def _exec_loop(self, info, rl, ins: Sequence[Any],
@@ -128,7 +155,6 @@ class ProgramVM:
         interpreter's op-by-op loop, so outputs are bitwise-identical."""
         body, lp = info.body, info.lp
         bprog = info.body_program
-        params = rl.rbody.params
         nc, nk = body.num_consts, body.num_carry
         consts_args = list(ins[:nc])
         carries = list(ins[nc:nc + nk])
@@ -141,27 +167,8 @@ class ProgramVM:
             flat = consts_args + carries + [
                 xs[j][i] if lp.x_used[j] else None for j in range(len(xs))]
             storage: List[Any] = [None] * bprog.n_regs
-            for inst in bprog.fast_instructions:
-                op = inst.op
-                if op == OP_COMPUTE:
-                    b_ins = [storage[r] for r in inst.in_regs]
-                    if inst.dim_as_value:
-                        out = jnp.asarray(params[inst.cidx]["dim"], jnp.int32)
-                        for _oi, r in inst.store:
-                            storage[r] = out
-                    elif inst.multi:
-                        outs = inst.prim.bind(*b_ins, **params[inst.cidx])
-                        for oi, r in inst.store:
-                            storage[r] = outs[oi]
-                    else:
-                        out = inst.prim.bind(*b_ins, **params[inst.cidx])
-                        for _oi, r in inst.store:
-                            storage[r] = out
-                elif op == OP_BIND_ARG:
-                    storage[inst.reg] = (flat[inst.index]
-                                         if inst.index >= 0 else inst.const)
-                elif op == OP_FREE_SLOT or op == OP_DONATE:
-                    storage[inst.reg] = None
+            # a body holds no rolled loop (bodies trace with roll_loops off)
+            self._run_range(storage, flat, rl.rbody, bprog.fast_instructions)
             carries = [storage[r] for r in out_regs[:nk]]
             for j, r in enumerate(out_regs[nk:]):
                 ys[j].append(storage[r])
@@ -178,12 +185,27 @@ class ProgramVM:
         return carries + stacked
 
     # ------------------------------------------------------------ fast path
-    def _run_fast(self, flat_args: Sequence[Any],
-                  resolved: ResolvedProgram) -> Tuple[List[Any], MemoryStats]:
+    def _run_fast(self, flat_args: Sequence[Any], resolved: ResolvedProgram,
+                  prof: Any = None) -> Tuple[List[Any], MemoryStats]:
+        """The fast stream: one run over the whole instruction list, or,
+        in a profiled call, one run per op group inside its span."""
         prog = self.program
         storage: List[Any] = [None] * prog.n_regs
+        if prof is None:
+            self._run_range(storage, flat_args, resolved,
+                            prog.fast_instructions)
+        else:
+            prof.run_groups(self, storage, flat_args, resolved)
+        outputs = [storage[r] for r in prog.out_regs]
+        return outputs, prog.stats_for(resolved)
+
+    def _run_range(self, storage: List[Any], flat_args: Sequence[Any],
+                   resolved: ResolvedProgram, insts: Sequence[Any]) -> None:
+        """The fast stream's loop body (its only copy) over ``insts``: gather
+        input registers, bind, store outputs, null dead registers."""
+        prog = self.program
         params = resolved.params
-        for inst in prog.fast_instructions:
+        for inst in insts:
             op = inst.op
             if op == OP_COMPUTE:
                 ins = [storage[r] for r in inst.in_regs]
@@ -210,50 +232,22 @@ class ProgramVM:
                     [storage[r] for r in inst.in_regs], resolved.env)
                 for oi, r in inst.store:
                     storage[r] = outs[oi]
-        outputs = [storage[r] for r in prog.out_regs]
-        return outputs, prog.stats_for(resolved)
 
     # -------------------------------------------------- fast path, faulted
     def _run_fast_faulted(self, flat_args: Sequence[Any],
                           resolved: ResolvedProgram,
                           faults: Any) -> Tuple[List[Any], MemoryStats]:
-        """``_run_fast`` with a fault probe ahead of every kernel bind.
+        """The fast stream one instruction at a time, with a fault probe
+        ahead of every kernel bind (a rolled loop counts as one step).
 
-        A separate loop so the clean fast stream stays branch-free: the
-        zero-overhead contract is on ``_run_fast``, this copy only runs
-        when a kernel fault is armed for the call."""
+        Only runs when a kernel fault is armed for the call, so the clean
+        stream's loop stays branch-free."""
         prog = self.program
         storage: List[Any] = [None] * prog.n_regs
-        params = resolved.params
         for inst in prog.fast_instructions:
-            op = inst.op
-            if op == OP_COMPUTE:
+            if inst.op == OP_COMPUTE or inst.op == OP_LOOP:
                 faults.before_compute()
-                ins = [storage[r] for r in inst.in_regs]
-                if inst.dim_as_value:
-                    out = jnp.asarray(params[inst.cidx]["dim"], jnp.int32)
-                    for _oi, r in inst.store:
-                        storage[r] = out
-                elif inst.multi:
-                    outs = inst.prim.bind(*ins, **params[inst.cidx])
-                    for oi, r in inst.store:
-                        storage[r] = outs[oi]
-                else:
-                    out = inst.prim.bind(*ins, **params[inst.cidx])
-                    for _oi, r in inst.store:
-                        storage[r] = out
-            elif op == OP_BIND_ARG:
-                storage[inst.reg] = (flat_args[inst.index]
-                                     if inst.index >= 0 else inst.const)
-            elif op == OP_FREE_SLOT or op == OP_DONATE:
-                storage[inst.reg] = None
-            elif op == OP_LOOP:
-                faults.before_compute()   # a rolled loop counts as one step
-                outs = self._exec_loop(
-                    prog.loops[inst.lidx], resolved.loops[inst.lidx],
-                    [storage[r] for r in inst.in_regs], resolved.env)
-                for oi, r in inst.store:
-                    storage[r] = outs[oi]
+            self._run_range(storage, flat_args, resolved, (inst,))
         outputs = [storage[r] for r in prog.out_regs]
         return outputs, prog.stats_for(resolved)
 

@@ -71,15 +71,19 @@ class Value:
 class Node:
     """One primitive application."""
 
-    __slots__ = ("id", "prim", "prim_name", "invals", "outvals", "params", "source_eqn")
+    __slots__ = ("id", "prim", "prim_name", "invals", "outvals", "params", "label")
 
-    def __init__(self, nid: int, prim: Any, invals: List[Value], outvals: List[Value], params: Dict[str, Any]):
+    def __init__(self, nid: int, prim: Any, invals: List[Value], outvals: List[Value],
+                 params: Dict[str, Any], label: str = ""):
         self.id = nid
         self.prim = prim
         self.prim_name = prim.name if prim is not None else "<none>"
         self.invals = invals
         self.outvals = outvals
         self.params = params
+        # op-group label from the traced eqn's name stack (ir.trace):
+        # ``fwd/layer0/attn``, ``bwd/layer0/mlp``, ``adamw``; "" unscoped
+        self.label = label
 
     def __repr__(self) -> str:  # pragma: no cover
         return f"({self.id}:{self.prim_name} {self.invals} -> {self.outvals})"
@@ -118,8 +122,9 @@ class Graph:
         self.values.append(v)
         return v
 
-    def add_node(self, prim, invals: Sequence[Value], outvals: Sequence[Value], params) -> Node:
-        n = Node(next(self._nid), prim, list(invals), list(outvals), dict(params))
+    def add_node(self, prim, invals: Sequence[Value], outvals: Sequence[Value], params,
+                 label: str = "") -> Node:
+        n = Node(next(self._nid), prim, list(invals), list(outvals), dict(params), label)
         for i, ov in enumerate(outvals):
             ov.producer = n
             ov.out_index = i

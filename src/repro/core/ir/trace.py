@@ -11,6 +11,7 @@ as a sub-graph so the downstream plan is O(body), not O(t·body).
 """
 from __future__ import annotations
 
+import re
 from typing import Any, Callable, Dict, List, Sequence, Tuple
 
 import jax
@@ -32,6 +33,35 @@ _INLINE_OPEN = {"remat2": "jaxpr", "checkpoint": "jaxpr", "remat": "jaxpr"}
 
 def _dims_of_aval(aval) -> Tuple[SymbolicExpr, ...]:
     return tuple(dim_to_expr(d) for d in aval.shape)
+
+
+_TRANSFORM = re.compile(r"\w+\(")
+# scopes kept in a label: the model's own (``layer0/attn``); deeper ones,
+# such as the spec jnp.einsum scopes its ops with, fold into them
+_LABEL_DEPTH = 2
+
+
+def scope_label(name_stack: str, parent: str = "") -> str:
+    """An eqn's op-group label from its ``jax.named_scope`` name stack.
+
+    Transforms are dropped and name the pass instead: ``jvp(layer0)/attn``
+    becomes ``fwd/layer0/attn``, ``transpose(jvp(layer0))/attn``
+    ``bwd/layer0/attn``; a stack outside any differentiation keeps its
+    scopes (``adamw``).  At most the outer two scopes are kept.  An eqn
+    inlined from a ``pjit`` / ``custom_jvp`` body extends its parent eqn's
+    label (``parent``), whose pass wins."""
+    kind, scopes = "", []
+    if parent:
+        scopes = parent.split("/")
+        if scopes[0] in ("fwd", "bwd"):
+            kind, scopes = scopes[0], scopes[1:]
+    elif name_stack.startswith("transpose("):
+        kind = "bwd"
+    elif name_stack.startswith("jvp("):
+        kind = "fwd"
+    scopes += [p for p in _TRANSFORM.sub("", name_stack).replace(")", "")
+               .split("/") if p]
+    return "/".join(([kind] if kind else []) + scopes[:_LABEL_DEPTH])
 
 
 def _try_roll_scan(eqn, *, name: str) -> "LoopBody | None":
@@ -172,11 +202,12 @@ def graph_from_closed_jaxpr(closed, *, name: str = "",
         g.consts.append(v)
         write(var, v)
 
-    def process(jaxpr, read_local, write_local):
+    def process(jaxpr, read_local, write_local, parent=""):
         for eqn in jaxpr.eqns:
             pname = eqn.primitive.name
+            label = scope_label(str(eqn.source_info.name_stack), parent)
             if pname in _INLINE_CLOSED or pname in _INLINE_OPEN:
-                _inline(eqn, read_local, write_local)
+                _inline(eqn, read_local, write_local, label)
                 continue
             if pname == "scan" and roll_loops:
                 body = _try_roll_scan(eqn, name=name)
@@ -191,7 +222,7 @@ def graph_from_closed_jaxpr(closed, *, name: str = "",
                         if not isinstance(ov, jcore.DropVar):
                             write_local(ov, val)
                     g.add_node(eqn.primitive, invals, outvals,
-                               {LOOP_PARAM: body})
+                               {LOOP_PARAM: body}, label)
                     continue
             invals = [read_local(v) for v in eqn.invars]
             outvals = []
@@ -201,9 +232,10 @@ def graph_from_closed_jaxpr(closed, *, name: str = "",
                 outvals.append(val)
                 if not isinstance(ov, jcore.DropVar):
                     write_local(ov, val)
-            _introduce(g.add_node(eqn.primitive, invals, outvals, eqn.params))
+            _introduce(g.add_node(eqn.primitive, invals, outvals, eqn.params,
+                                  label))
 
-    def _inline(eqn, read_outer, write_outer):
+    def _inline(eqn, read_outer, write_outer, label):
         pname = eqn.primitive.name
         local_env: Dict[Any, Value] = {}
 
@@ -236,7 +268,7 @@ def graph_from_closed_jaxpr(closed, *, name: str = "",
             assert not inner.constvars, f"{pname} with constvars unsupported"
         for var, val in zip(inner.invars, outer_invals):
             write_inner(var, val)
-        process(inner, read_inner, write_inner)
+        process(inner, read_inner, write_inner, label)
         for outer_var, inner_var in zip(eqn.outvars, inner.outvars):
             if isinstance(outer_var, jcore.DropVar):
                 continue

@@ -27,8 +27,34 @@ from ..ir.loop import loop_body_of
 from ..ir.trace import _contains_symbolic
 from ..remat.export import export_regen_programs
 from ..remat.planner import ExecutionPlan
-from .program import (BindArg, BindDim, Compute, Donate, FreeSlot, Loop,
-                      LoopInfo, MaybeEvict, Program, Regen, Return)
+from .program import (OP_COMPUTE, OP_LOOP, BindArg, BindDim, Compute, Donate,
+                      FreeSlot, Loop, LoopInfo, MaybeEvict, OpGroup, Program,
+                      Regen, Return)
+
+
+def op_groups(fast: List[Any], loops: List[LoopInfo]) -> List[OpGroup]:
+    """Cut the fast stream into contiguous runs of one op-group label.
+
+    A Compute or Loop whose label differs from the open run's starts a new
+    run (a Loop always does); other instructions (BindArg, frees, Return)
+    join the open run, and those before the first Compute join the first."""
+    starts: List[Tuple[int, str, bool]] = []
+    for i, inst in enumerate(fast):
+        if inst.op == OP_COMPUTE:
+            label, loop = inst.node.label, False
+        elif inst.op == OP_LOOP:
+            label, loop = loops[inst.lidx].node.label, True
+        else:
+            continue
+        if starts and not loop and not starts[-1][2] \
+                and label == starts[-1][1]:
+            continue
+        starts.append((i if starts else 0, label, loop))
+    if not starts:
+        return [OpGroup("", 0, len(fast), False)] if fast else []
+    ends = [lo for lo, _, _ in starts[1:]] + [len(fast)]
+    return [OpGroup(label, lo, hi, loop)
+            for (lo, label, loop), hi in zip(starts, ends)]
 
 
 def lower_plan(plan: ExecutionPlan, *,
@@ -213,4 +239,5 @@ def lower_plan(plan: ExecutionPlan, *,
                    has_evict_path=has_evict_path,
                    memory_limit=memory_limit, donate_inputs=donate_inputs,
                    count_inputs=count_inputs, loops=loops,
-                   bound_dep_regs=bound_dep_regs)
+                   bound_dep_regs=bound_dep_regs,
+                   groups=op_groups(fast, loops))

@@ -41,7 +41,7 @@ Return      gather the output registers
 from __future__ import annotations
 
 from dataclasses import dataclass, field, replace
-from typing import Any, Dict, List, Optional, Tuple
+from typing import Any, Dict, List, NamedTuple, Optional, Tuple
 
 from ..executor.memory import MemoryManager, MemoryStats
 from ..ir.graph import Graph, Node
@@ -189,6 +189,17 @@ class Loop:
     op: int = OP_LOOP
 
 
+class OpGroup(NamedTuple):
+    """A contiguous run ``[lo, hi)`` of the fast stream whose Computes share
+    one op-group label (``Node.label``).  A rolled Loop is a run of its own
+    (``loop``).  The scheduler may interleave groups, so one label can give
+    several runs; the runs cover the stream exactly once, in order."""
+    label: str
+    lo: int
+    hi: int
+    loop: bool
+
+
 @dataclass
 class LoopInfo:
     """Compile-time half of one rolled loop inside a Program."""
@@ -264,6 +275,10 @@ class ResolvedProgram:
     # per rolled loop (index = Loop.lidx): trip count, body resolve,
     # exact internal peak delta, and the accounting size tables
     loops: List[ResolvedLoop] = field(default_factory=list)
+    # the plan's device occupancy at the end of each op group
+    # (``Program.groups``), filled on a profiled call's first use of this
+    # env (``obs.profile``)
+    group_plan_bytes: Optional[List[int]] = None
 
 
 @dataclass
@@ -296,6 +311,9 @@ class Program:
     # bounded dim name -> registers whose byte size mentions it (refreshed
     # by the BindDim that publishes the measured value)
     bound_dep_regs: Dict[str, Tuple[int, ...]] = field(default_factory=dict)
+    # the fast stream cut into op-group runs (a profiled call opens one
+    # span per run)
+    groups: List[OpGroup] = field(default_factory=list)
 
     def __post_init__(self):
         self._resolve_cache: Dict[Tuple, ResolvedProgram] = {}

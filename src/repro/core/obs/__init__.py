@@ -1,18 +1,22 @@
 """Plan-aware observability: compile tracing, runtime telemetry, exports.
 
-Three coordinated layers over the symbolic-shape pipeline:
+Coordinated layers over the symbolic-shape pipeline:
 
 * :mod:`.trace` — hierarchical compile-phase spans + a structured
   decision log, recorded by ``optimize`` and every bucket specialization
   (background compiles included, on their own track);
 * :mod:`.telemetry` — a fixed-capacity per-call ring buffer behind a
-  single disabled-path attribute check (the ≤2% overhead contract), plus
-  exact per-instruction memory timelines reconstructed off the hot path
-  (:mod:`.timeline`: the plan's symbolic events replayed at one env and
-  diffed against the plan's predicted occupancy);
+  single disabled-path attribute check (the ≤2% overhead contract);
+* :mod:`.timeline` — exact per-instruction memory timelines, off the hot
+  path: the plan's symbolic events replayed at one env and diffed
+  against the plan's predicted occupancy;
 * :mod:`.export` / :mod:`.explain` — Chrome-trace/Perfetto JSON,
   Prometheus text metrics, and the human-readable
-  ``DynamicShapeFunction.explain()`` report.
+  ``DynamicShapeFunction.explain()`` report;
+* :mod:`.profile` — under a ``jax.profiler`` session, spans inside each
+  call (solve, resolve, one per op group, Python GC) written into the
+  profiler's own trace, and one record per call in the process-wide
+  :data:`.telemetry.PROFILE_SINK`.
 """
 from .explain import build_explain
 from .export import chrome_trace, chrome_trace_json, prometheus_text

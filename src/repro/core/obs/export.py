@@ -1,8 +1,8 @@
 """Export surfaces: Chrome-trace JSON and Prometheus-style text metrics.
 
-``chrome_trace`` serializes a :class:`.trace.Tracer`'s span forest (plus,
-optionally, sampled runtime timelines) into the Trace Event Format that
-``chrome://tracing`` and Perfetto load directly: complete events
+``chrome_trace`` serializes a :class:`.trace.Tracer`'s span forest into
+the Trace Event Format that ``chrome://tracing`` and Perfetto load
+directly: complete events
 (``ph: "X"``) with microsecond timestamps, one track per recording thread
 — so background-specialize compiles render alongside the main thread's
 pipeline instead of interleaved with it.
@@ -15,7 +15,7 @@ arena-bound gauges, ready for a ``/metrics`` endpoint.
 from __future__ import annotations
 
 import json
-from typing import Any, Dict, List, Optional
+from typing import Any, Dict, List
 
 from .trace import Span
 
@@ -36,13 +36,8 @@ def _span_events(span: Span, pid: int, out: List[Dict[str, Any]]) -> None:
         _span_events(c, pid, out)
 
 
-def chrome_trace(tracer, timelines: Optional[List] = None,
-                 pid: int = 1) -> Dict[str, Any]:
-    """Trace Event Format dict for a Tracer (json.dump straight to disk).
-
-    ``timelines``: optional ``(seq, Timeline)`` pairs (e.g.
-    ``Telemetry.timelines``) appended as counter events (``ph: "C"``) so
-    the memory curve renders under the compile spans."""
+def chrome_trace(tracer, pid: int = 1) -> Dict[str, Any]:
+    """Trace Event Format dict for a Tracer (json.dump straight to disk)."""
     events: List[Dict[str, Any]] = []
     thread_names: Dict[int, str] = {}
     for root in getattr(tracer, "roots", []):
@@ -53,22 +48,11 @@ def chrome_trace(tracer, timelines: Optional[List] = None,
     # thread metadata first, so viewers label tracks by thread name
     meta = [{"name": "thread_name", "ph": "M", "pid": pid, "tid": tid,
              "args": {"name": name}} for tid, name in thread_names.items()]
-    for seq, tl in (timelines or []):
-        for pt in tl.points:
-            events.append({
-                "name": f"memory (call {seq})",
-                "ph": "C",
-                "ts": float(pt.idx),       # pseudo-time: program counter
-                "pid": pid,
-                "tid": 0,
-                "args": {"device_used": pt.device_used,
-                         "arena_in_use": pt.arena_in_use},
-            })
     return {"traceEvents": meta + events, "displayTimeUnit": "ms"}
 
 
-def chrome_trace_json(tracer, timelines: Optional[List] = None) -> str:
-    return json.dumps(chrome_trace(tracer, timelines))
+def chrome_trace_json(tracer) -> str:
+    return json.dumps(chrome_trace(tracer))
 
 
 # -- Prometheus text exposition ------------------------------------------------

@@ -16,17 +16,15 @@ single-threaded use.  Readers (``records()``) snapshot under the same
 lock; slots are replaced wholesale, so a reader can never observe a
 partial record.
 
-Per-instruction memory timelines are *not* sampled by instrumenting the
-VM fast stream — that would put a branch in the hottest loop.  Because
-the fast stream's memory traffic is fully determined by the env (the
-``Program.resolve`` replay fact), an enabled sampler reconstructs the
-exact timeline off the hot path via :func:`.timeline.actual_timeline`
-every ``sample_timeline_every``-th call.
+A second, process-wide ring, :data:`PROFILE_SINK`, takes one record per
+call made under a ``jax.profiler`` session, whatever the function's own
+telemetry says (:mod:`.profile`): its phase times, binds, GC pauses and
+HBM held above the plan are what the benchmark's per-layer metrics read.
 """
 from __future__ import annotations
 
 import threading
-from dataclasses import dataclass, field
+from dataclasses import dataclass
 from typing import Any, Dict, List, NamedTuple, Optional, Tuple
 
 
@@ -45,6 +43,20 @@ class CallRecord(NamedTuple):
     reloads: int
     donated_reuses: int
     loop_trips: Tuple[int, ...]         # per rolled loop, program order
+    # profiled calls only (obs.profile; 0 otherwise): host ns in the env
+    # solve + declared-range check, Program.resolve, the instruction
+    # stream, and the whole call (flatten to unflatten); primitive binds
+    # executed; Python GC pauses inside the call; the largest excess of
+    # the device's bytes in use over the plan's occupancy at an op-group
+    # boundary
+    solve_ns: int = 0
+    resolve_ns: int = 0
+    stream_ns: int = 0
+    call_ns: int = 0
+    binds: int = 0
+    gc_ns: int = 0
+    gc_collections: int = 0
+    hbm_over_plan_bytes: int = 0
 
 
 class TelemetryRing:
@@ -106,32 +118,30 @@ class AdmissionEvent:
 
 
 class Telemetry:
-    """Per-function telemetry aggregate: ring + running totals + sampled
-    timelines.  Created by ``DynamicShapeFunction.enable_telemetry()``.
-    Counter updates are lock-protected — concurrent request threads must
-    not lose increments (the lock is on the enabled path only)."""
+    """Telemetry aggregate: ring + running totals.  Created per function by
+    ``DynamicShapeFunction.enable_telemetry()``, and once per process as
+    :data:`PROFILE_SINK`.  Counter updates are lock-protected — concurrent
+    request threads must not lose increments (the lock is on the enabled
+    path only)."""
 
-    def __init__(self, capacity: int = 256, sample_timeline_every: int = 0,
-                 max_timelines: int = 8):
+    def __init__(self, capacity: int = 256):
         self.ring = TelemetryRing(capacity)
-        self.sample_timeline_every = sample_timeline_every
-        self.max_timelines = max_timelines
         self.n_calls = 0
         self.wall_s_total = 0.0
         self.dispatch_ns_total = 0
         self.calls_by_bucket: Dict[Optional[Tuple], int] = {}
-        # (seq, timeline) pairs, newest kept; see .timeline.actual_timeline
-        self.timelines: List[Tuple[int, Any]] = []
         self._lock = threading.Lock()
 
     def on_call(self, bucket_key: Optional[Tuple], report: Any, *,
-                program: Any = None,
-                loop_trips: Tuple[int, ...] = ()) -> None:
+                loop_trips: Tuple[int, ...] = (),
+                profile: Any = None) -> None:
         """Record one dispatched call.  Runs only when telemetry is
-        enabled — the disabled path never reaches this method."""
+        enabled or the call was profiled — the disabled path never reaches
+        this method.  ``profile`` (a :class:`.profile.CallProfile`) adds
+        the call's phase times and counters, under the call span's seq."""
         st = report.stats
         with self._lock:
-            seq = self.n_calls
+            seq = self.n_calls if profile is None else profile.seq
             self.n_calls += 1
             self.wall_s_total += report.wall_s
             self.dispatch_ns_total += st.last_dispatch_ns
@@ -144,13 +154,8 @@ class Telemetry:
             device_peak=st.device_peak, arena_bytes=st.arena_bytes,
             evictions=st.evictions, recomputes=st.recomputes,
             reloads=st.reloads, donated_reuses=st.donated_reuses,
-            loop_trips=loop_trips))
-        every = self.sample_timeline_every
-        if every and program is not None and seq % every == 0:
-            from .timeline import actual_timeline
-            self.timelines.append((seq, actual_timeline(program, report.env)))
-            if len(self.timelines) > self.max_timelines:
-                del self.timelines[:len(self.timelines) - self.max_timelines]
+            loop_trips=loop_trips,
+            **({} if profile is None else profile.counters())))
 
     def summary(self) -> Dict[str, Any]:
         return dict(
@@ -160,5 +165,9 @@ class Telemetry:
             ring_retained=len(self.ring),
             ring_dropped=self.ring.dropped,
             calls_by_bucket={str(k): v
-                             for k, v in self.calls_by_bucket.items()},
-            timelines_sampled=len(self.timelines))
+                             for k, v in self.calls_by_bucket.items()})
+
+
+# every call made under a jax.profiler session, process-wide (obs.profile);
+# the benchmark's readers take its newest records
+PROFILE_SINK = Telemetry(capacity=256)

@@ -9,9 +9,10 @@ thread's tree — Chrome trace viewers render the two as separate tracks.
 
 Tracing is always on at compile time: a compile emits a handful of spans,
 so the cost is nanoseconds against a pipeline that runs milliseconds.
-The *runtime* hot path is a different story and never touches this module
-(see :mod:`.telemetry` for the per-call ring buffer and its overhead
-contract).
+Each span also enters a ``jax.profiler.TraceAnnotation`` of its name, so
+under a profiler session the phases show in the device trace's timeline
+(background compiles on their own thread).  The *runtime* hot path never
+touches this module (see :mod:`.telemetry` and :mod:`.profile`).
 
 ``DecisionLog`` records the compile decisions that are only observable
 *while* they happen — exchange-pass swaps, the schedule guard's
@@ -27,6 +28,8 @@ import time
 from contextlib import contextmanager
 from dataclasses import dataclass, field
 from typing import Any, Dict, Iterator, List, Optional
+
+from jax.profiler import TraceAnnotation
 
 
 @dataclass
@@ -93,7 +96,8 @@ class Tracer:
                     del self.roots[:len(self.roots) - self.max_roots]
         stack.append(s)
         try:
-            yield s
+            with TraceAnnotation(name):
+                yield s
         finally:
             s.t1_ns = time.perf_counter_ns()
             stack.pop()

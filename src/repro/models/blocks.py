@@ -88,32 +88,38 @@ def block_init(key, cfg, dtype=jnp.float32) -> Dict:
 
 def block_apply(params: Dict, cfg, x: jax.Array, *, window=None,
                 q_offset=0) -> Tuple[jax.Array, jax.Array]:
-    """Full-sequence block forward. Returns (y, moe_aux)."""
+    """Full-sequence block forward. Returns (y, moe_aux).
+
+    The two halves, residual add included, run under the ``attn`` and
+    ``mlp`` name scopes: the op groups a profile of the step shows."""
     aux = jnp.zeros((), jnp.float32)
     x = shard_hint(x, DP, None, None)
-    h = rms_norm(x, params["ln1"])
-    if cfg.attn_kind == "mla":
-        attn_out, _ = mla_prefill(params["attn"], cfg.mla_config(), h,
-                                  q_offset=q_offset)
-    else:
-        attn_out = gqa_full(params["attn"], h, n_heads=cfg.n_heads,
-                            n_kv_heads=cfg.n_kv_heads,
-                            head_dim=cfg.resolved_head_dim,
-                            rope_theta=cfg.rope_theta, q_offset=q_offset,
-                            window=window)
-    if cfg.family == "hybrid":  # parallel attn + SSM heads (hymba)
-        ssm_out = ssm_apply(params["ssm"], cfg.ssm_config(),
-                            rms_norm(x, params["ln_ssm"]))
-        attn_out = 0.5 * (attn_out + ssm_out)
-    x = x + attn_out
-    if cfg.n_experts:
-        y, aux = moe_apply(params["moe"], rms_norm(x, params["ln2"]),
-                           top_k=cfg.top_k, capacity_factor=cfg.moe_capacity,
-                           ffn_kind=cfg.ffn_kind)
-        x = x + y
-    elif cfg.d_ff:
-        x = x + ffn_apply(params["ffn"], rms_norm(x, params["ln2"]),
-                          kind=cfg.ffn_kind)
+    with jax.named_scope("attn"):
+        h = rms_norm(x, params["ln1"])
+        if cfg.attn_kind == "mla":
+            attn_out, _ = mla_prefill(params["attn"], cfg.mla_config(), h,
+                                      q_offset=q_offset)
+        else:
+            attn_out = gqa_full(params["attn"], h, n_heads=cfg.n_heads,
+                                n_kv_heads=cfg.n_kv_heads,
+                                head_dim=cfg.resolved_head_dim,
+                                rope_theta=cfg.rope_theta, q_offset=q_offset,
+                                window=window)
+        if cfg.family == "hybrid":  # parallel attn + SSM heads (hymba)
+            ssm_out = ssm_apply(params["ssm"], cfg.ssm_config(),
+                                rms_norm(x, params["ln_ssm"]))
+            attn_out = 0.5 * (attn_out + ssm_out)
+        x = x + attn_out
+    with jax.named_scope("mlp"):
+        if cfg.n_experts:
+            y, aux = moe_apply(params["moe"], rms_norm(x, params["ln2"]),
+                               top_k=cfg.top_k,
+                               capacity_factor=cfg.moe_capacity,
+                               ffn_kind=cfg.ffn_kind)
+            x = x + y
+        elif cfg.d_ff:
+            x = x + ffn_apply(params["ffn"], rms_norm(x, params["ln2"]),
+                              kind=cfg.ffn_kind)
     return x, aux
 
 

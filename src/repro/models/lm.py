@@ -136,10 +136,12 @@ def _scan_blocks(cfg: ModelConfig, params: Dict, x: jax.Array,
         # schedules / rematerializes (remat is *its* job, not jax.checkpoint's)
         aux = jnp.zeros((), jnp.float32)
         for i in range(cfg.n_layers):
-            layer_p = jax.tree.map(lambda a: a[i], params["layers"])
-            w = None if windows is None else int(windows[i])
-            x, a = block_apply(layer_p, cfg, x, window=w, q_offset=q_offset)
-            aux = aux + a
+            with jax.named_scope(f"layer{i}"):
+                layer_p = jax.tree.map(lambda a: a[i], params["layers"])
+                w = None if windows is None else int(windows[i])
+                x, a = block_apply(layer_p, cfg, x, window=w,
+                                   q_offset=q_offset)
+                aux = aux + a
         return x, aux
 
     def body(carry, xs):
@@ -179,27 +181,35 @@ def _xlstm_forward(cfg: ModelConfig, params: Dict, x: jax.Array) -> jax.Array:
     return x
 
 
+def _forward_hidden(cfg: ModelConfig, params: Dict,
+                    batch: Dict) -> Tuple[jax.Array, jax.Array]:
+    """The final hidden states and the MoE aux loss, under the ``embed``
+    and ``layer{i}`` name scopes."""
+    with jax.named_scope("embed"):
+        x = _embed_inputs(cfg, params, batch)
+    if cfg.block_kind == "xlstm":
+        return _xlstm_forward(cfg, params, x), jnp.zeros((), jnp.float32)
+    return _scan_blocks(cfg, params, x)
+
+
 def forward(cfg: ModelConfig, params: Dict, batch: Dict) -> Tuple[jax.Array, jax.Array]:
     """Returns (logits, moe_aux_loss)."""
-    x = _embed_inputs(cfg, params, batch)
-    if cfg.block_kind == "xlstm":
-        h = _xlstm_forward(cfg, params, x)
-        aux = jnp.zeros((), jnp.float32)
-    else:
-        h, aux = _scan_blocks(cfg, params, x)
+    h, aux = _forward_hidden(cfg, params, batch)
     return _lm_logits(cfg, params, h), aux
 
 
 def loss_fn(cfg: ModelConfig, params: Dict, batch: Dict) -> jax.Array:
-    logits, aux = forward(cfg, params, batch)
-    labels = batch["labels"]
-    if cfg.input_mode == "vlm":  # loss only over text positions
-        logits = logits[:, -labels.shape[1]:]
-    if cfg.n_codebooks:
-        loss = softmax_cross_entropy(logits, labels)     # labels (B,S,K)
-    else:
-        loss = softmax_cross_entropy(logits, labels, batch.get("mask"))
-    return loss + 0.01 * aux
+    h, aux = _forward_hidden(cfg, params, batch)
+    with jax.named_scope("head_loss"):
+        logits = _lm_logits(cfg, params, h)
+        labels = batch["labels"]
+        if cfg.input_mode == "vlm":  # loss only over text positions
+            logits = logits[:, -labels.shape[1]:]
+        if cfg.n_codebooks:
+            loss = softmax_cross_entropy(logits, labels)  # labels (B,S,K)
+        else:
+            loss = softmax_cross_entropy(logits, labels, batch.get("mask"))
+        return loss + 0.01 * aux
 
 
 # -- serving: prefill + single-token decode -------------------------------------------

@@ -36,6 +36,7 @@ def global_norm(tree) -> jax.Array:
                         for x in leaves))
 
 
+@jax.named_scope("adamw")
 def apply_updates(params, grads, state: AdamWState,
                   cfg: AdamWConfig = AdamWConfig()) -> Tuple[Any, AdamWState]:
     step = state.step + 1

@@ -188,7 +188,10 @@ def test_measured_zero_frees_and_reuses():
         for a, b in zip(o_vm, o_ref):
             assert np.array_equal(np.asarray(a), np.asarray(b))
         sv, sr = fn.last_report.stats, ref.last_report.stats
-        assert sv.as_dict() == sr.as_dict()
+        # every counter but the dispatch wall times (not semantics)
+        timing = ("last_dispatch_ns", "dispatch_ns_total")
+        assert {k: v for k, v in sv.as_dict().items() if k not in timing} \
+            == {k: v for k, v in sr.as_dict().items() if k not in timing}
         if occ == 0.0:
             assert list(sv.measured_dims.values()) == [0]
             # eager oracle agrees the selection is empty

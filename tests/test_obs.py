@@ -1,6 +1,6 @@
 """Observability contracts: tracing, telemetry, timelines, exports.
 
-Four contract families over :mod:`repro.core.obs`:
+Five contract families over :mod:`repro.core.obs`:
 
   * **tracing** — ``optimize`` records a span forest (phases nested under
     their parent, attributes attached) and a decision log; the
@@ -17,12 +17,18 @@ Four contract families over :mod:`repro.core.obs`:
     unexplained allocations, device peak exactly the plan's prediction —
     including across a rolled ``lax.scan`` loop;
   * **serve surfaces** — admission control emits structured events and
-    the Prometheus export renders well-formed metric families.
+    the Prometheus export renders well-formed metric families;
+  * **profiled calls** — under a ``jax.profiler`` session a call writes
+    its spans (call, solve, resolve, one per op group, GC) into the
+    profiler's trace and one record to the process-wide sink; outside a
+    session it records nothing, and its outputs are the same bits.
 """
+import gc
 import json
 import os
 import threading
 import tracemalloc
+import warnings
 
 import jax
 import jax.numpy as jnp
@@ -33,9 +39,11 @@ from hypothesis import strategies as st
 
 import repro.core.obs as obs_pkg
 from repro.core import optimize, symbolic_dim, symbolic_dims
+from repro.core.lowering.program import OP_COMPUTE
 from repro.core.obs import (CallRecord, DecisionLog, NullTracer,
-                            TelemetryRing, Tracer, chrome_trace,
-                            chrome_trace_json, prometheus_text)
+                            TelemetryRing, Tracer, chrome_trace_json,
+                            prometheus_text)
+from repro.core.obs.telemetry import PROFILE_SINK
 from repro.launch.serve import BucketBatcher
 
 
@@ -172,13 +180,6 @@ class TestChromeTrace:
             for v in e["args"].values():   # JSON-safe attrs only
                 assert isinstance(v, (int, float, str, bool, type(None)))
 
-    def test_counter_events_from_timelines(self, chain_fn):
-        diff = chain_fn.memory_timeline({"n": 8})
-        data = chrome_trace(chain_fn.trace, timelines=[(0, diff.actual)])
-        counters = [e for e in data["traceEvents"] if e["ph"] == "C"]
-        assert len(counters) == len(diff.actual.points)
-        assert counters[0]["args"]["device_used"] >= 0
-
 
 # -- telemetry -----------------------------------------------------------------
 
@@ -213,8 +214,7 @@ def test_ring_rejects_zero_capacity():
 class TestTelemetry:
     def test_enable_record_disable(self, bucketed_fn):
         w = np.ones((8, 8), np.float32)
-        tel = bucketed_fn.enable_telemetry(capacity=4,
-                                           sample_timeline_every=2)
+        tel = bucketed_fn.enable_telemetry(capacity=4)
         try:
             for b in (2, 2, 30):
                 bucketed_fn(w, np.ones((b, 8), np.float32))
@@ -226,8 +226,6 @@ class TestTelemetry:
             assert recs[0].bucket_key is not None
             assert recs[0].bucket_key == recs[1].bucket_key
             assert recs[2].bucket_key != recs[0].bucket_key
-            # every-2nd-call sampling: calls 0 and 2
-            assert [seq for seq, _tl in tel.timelines] == [0, 2]
             assert tel.summary()["n_calls"] == 3
         finally:
             got = bucketed_fn.disable_telemetry()
@@ -248,6 +246,29 @@ class TestTelemetry:
         d = st2.as_dict()
         assert "last_dispatch_ns" in d and "dispatch_ns_total" in d
         assert "dispatch_ns" not in d
+
+    def test_unbucketed_dispatch_is_timed(self, chain_fn):
+        """The monolithic path times its env solve, range check and
+        resolve, so every dispatch counter reads non-zero there too."""
+        x = np.ones((4, 4), np.float32)
+        tel = chain_fn.enable_telemetry()
+        try:
+            chain_fn(x)
+            st1 = chain_fn.last_report.stats
+            assert chain_fn.last_bucket is None
+            assert st1.last_dispatch_ns > 0
+            chain_fn(x)
+            st2 = chain_fn.last_report.stats
+            assert st2.dispatch_ns_total >= (st1.dispatch_ns_total
+                                             + st2.last_dispatch_ns)
+            assert tel.dispatch_ns_total == (st1.last_dispatch_ns
+                                             + st2.last_dispatch_ns)
+            text = prometheus_text(chain_fn)
+            line, = [ln for ln in text.splitlines()
+                     if ln.startswith("repro_dispatch_ns_total ")]
+            assert int(line.split()[1]) > 0
+        finally:
+            chain_fn.disable_telemetry()
 
     def test_loop_trips_recorded(self, loop_fn):
         tel = loop_fn.enable_telemetry()
@@ -276,6 +297,151 @@ class TestTelemetry:
         diff = after.compare_to(before, "lineno")
         grew = [d for d in diff if d.size_diff > 0]
         assert not grew, f"obs code allocated on the disabled path: {grew}"
+
+
+# -- profiled calls (a jax.profiler session on the CPU) -------------------------
+
+def _trace_events(log_dir):
+    """(name, metadata) of every host event of the trace under log_dir."""
+    from jax.profiler import ProfileData
+    path, = [os.path.join(d, f) for d, _, fs in os.walk(log_dir)
+             for f in fs if f.endswith(".xplane.pb")]
+    out = []
+    with warnings.catch_warnings():      # jaxlib's stats type warns on use
+        warnings.simplefilter("ignore", DeprecationWarning)
+        for plane in ProfileData.from_file(path).planes:
+            if plane.name.startswith("/host:CPU"):
+                for line in plane.lines:
+                    out += [(e.name, dict(e.stats)) for e in line.events]
+    return out
+
+
+def _computes(prog):
+    return sum(i.op == OP_COMPUTE for i in prog.fast_instructions)
+
+
+@pytest.fixture(scope="module")
+def train_fn():
+    """The trainer's symbolic step on a two-layer model at tiny widths."""
+    from repro.configs.base import ModelConfig
+    from repro.launch.steps import adamw_config_for
+    from repro.launch.train import build_dynamic_step
+    from repro.models import init_params
+    from repro.optim import init_state
+    cfg = ModelConfig(name="tiny", family="dense", n_layers=2, d_model=32,
+                      n_heads=4, n_kv_heads=2, d_ff=64, vocab=64,
+                      ffn_kind="swiglu", rope_theta=1e4,
+                      tie_embeddings=False, dtype="bfloat16",
+                      optimizer_dtype="float32")
+    params = init_params(cfg, jax.random.PRNGKey(0))
+    opt = init_state(params, adamw_config_for(cfg))
+    batch = {"tokens": jnp.ones((2, 12), jnp.int32),
+             "labels": jnp.ones((2, 12), jnp.int32),
+             "mask": jnp.ones((2, 12), jnp.float32)}
+    return build_dynamic_step(cfg, params, opt), (params, opt, batch)
+
+
+class TestProfiledCall:
+    def test_a_session_records_one_call(self, chain_fn, tmp_path):
+        x = np.ones((6, 4), np.float32)
+        chain_fn(x)
+        before = PROFILE_SINK.ring.total_pushed
+        with jax.profiler.trace(str(tmp_path)):
+            chain_fn(x)
+        assert PROFILE_SINK.ring.total_pushed == before + 1
+        rec = PROFILE_SINK.ring.records()[-1]
+        assert rec.env == (("n", 6),)
+        assert rec.binds == _computes(chain_fn.program) > 0
+        for f in ("solve_ns", "resolve_ns", "stream_ns", "call_ns",
+                  "dispatch_ns"):
+            assert getattr(rec, f) > 0, f
+        assert rec.call_ns >= rec.stream_ns + rec.resolve_ns
+        names = {n for n, _ in _trace_events(tmp_path)}
+        assert {"dsf.call", "dsf.solve_env", "vm.resolve", "vm.stream",
+                "vm.ops"} <= names
+        call, = [m for n, m in _trace_events(tmp_path) if n == "dsf.call"]
+        assert call["seq"] == rec.seq and call["n"] == 6
+
+    def test_rolled_loop_runs_in_a_loop_span(self, loop_fn, tmp_path):
+        h0, xs = np.ones(4, np.float32), np.ones((5, 4), np.float32)
+        plain = loop_fn(h0, xs)
+        with jax.profiler.trace(str(tmp_path)):
+            traced = loop_fn(h0, xs)
+        np.testing.assert_array_equal(np.asarray(plain), np.asarray(traced))
+        prog = loop_fn.program
+        body, = [info.body_program for info in prog.loops]
+        rec = PROFILE_SINK.ring.records()[-1]
+        assert rec.loop_trips == (5,)
+        assert rec.binds == _computes(prog) + 5 * _computes(body)
+        loops = [m for n, m in _trace_events(tmp_path) if n == "vm.loop"]
+        assert loops == [{"trips": 5}]
+
+    def test_no_session_records_nothing(self, chain_fn):
+        from jax.profiler import TraceAnnotation
+        assert not TraceAnnotation.is_enabled()
+        before = PROFILE_SINK.ring.total_pushed
+        chain_fn(np.ones((6, 4), np.float32))
+        assert PROFILE_SINK.ring.total_pushed == before
+
+    def test_groups_cover_the_stream_and_change_no_output(self, train_fn,
+                                                          tmp_path):
+        fn, (params, opt, batch) = train_fn
+        prog = fn.program
+        edges = [(g.lo, g.hi) for g in prog.groups]
+        assert edges[0][0] == 0 and edges[-1][1] == len(
+            prog.fast_instructions)
+        assert all(a[1] == b[0] and a[0] < a[1]
+                   for a, b in zip(edges, edges[1:]))
+        plain = fn(params, opt, batch)
+        with jax.profiler.trace(str(tmp_path)):
+            traced = fn(params, opt, batch)
+        for a, b in zip(jax.tree.leaves(plain), jax.tree.leaves(traced)):
+            np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+        rec = PROFILE_SINK.ring.records()[-1]
+        assert rec.binds == _computes(prog)
+        spans = [m for n, m in _trace_events(tmp_path)
+                 if n in {g.label for g in prog.groups}]
+        assert len(spans) == len(prog.groups)
+        assert max(m["hbm_bytes"] - m["plan_bytes"] for m in spans) \
+            == rec.hbm_over_plan_bytes
+
+    def test_named_scopes_label_forward_and_backward(self, train_fn):
+        labels = {g.label for g in train_fn[0].program.groups}
+        for want in ("fwd/embed", "fwd/layer0/attn", "fwd/layer1/mlp",
+                     "fwd/head_loss", "bwd/head_loss", "bwd/layer0/attn",
+                     "bwd/layer1/mlp", "adamw"):
+            assert want in labels, (want, sorted(labels))
+
+    def test_gc_callbacks_balance_and_count(self, chain_fn, monkeypatch,
+                                            tmp_path):
+        from repro.core.executor.vm import ProgramVM
+        run_range = ProgramVM._run_range
+
+        def collecting(self, *a):
+            gc.collect()
+            return run_range(self, *a)
+
+        monkeypatch.setattr(ProgramVM, "_run_range", collecting)
+        x = np.ones((6, 4), np.float32)
+        n_callbacks = len(gc.callbacks)
+        with jax.profiler.trace(str(tmp_path)):
+            chain_fn(x)
+        assert len(gc.callbacks) == n_callbacks
+        rec = PROFILE_SINK.ring.records()[-1]
+        assert rec.gc_collections >= 1 and rec.gc_ns > 0
+        gcs = [m for n, m in _trace_events(tmp_path) if n == "python.gc"]
+        assert len(gcs) == rec.gc_collections
+        assert all(m["generation"] == 2 for m in gcs)
+
+    def test_compile_spans_reach_the_profile(self, tmp_path):
+        tr = Tracer()
+        with jax.profiler.trace(str(tmp_path)):
+            with tr.span("memplan"):
+                with tr.span("lower"):
+                    pass
+        names = [n for n, _ in _trace_events(tmp_path)]
+        assert "memplan" in names and "lower" in names
+        assert [s.name for s in tr.spans()] == ["memplan", "lower"]
 
 
 # -- timelines -----------------------------------------------------------------
