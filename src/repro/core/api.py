@@ -666,17 +666,26 @@ class DynamicShapeFunction:
         attempt = 0
         force_fb = False
         degraded = False
+        opened = None          # the bucket this call's compile fault opened
         while True:
             try:
-                return self._dispatch(flat, force_fallback=force_fb,
+                outs = self._dispatch(flat, force_fallback=force_fb,
                                       faults=armed, prof=prof)
             except (TransientKernelError, RegenFailure,
                     OffloadFailure) as e:
                 err, rung, fb_next = e, "retry-transient", force_fb
             except (MemoryLimitExceeded, ArenaExhausted) as e:
                 err, rung, fb_next = e, "retry-fallback", True
-            except (CompileFault, BucketQuarantined) as e:
+            except CompileFault as e:
                 err, rung, fb_next = e, "retry-fallback", True
+                opened = self._table.key_of(solve_env(self.plan.graph, flat))
+            except BucketQuarantined as e:
+                err, rung, fb_next = e, "retry-fallback", True
+            else:
+                if opened is not None:
+                    # the quarantine runs from when the fallback served
+                    self._table.breaker.restart_backoff(opened)
+                return outs
             if not degraded:
                 degraded = True
                 res.note_degraded_call()

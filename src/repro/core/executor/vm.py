@@ -4,27 +4,39 @@ The default executor.  Two regimes, chosen per dim binding by
 ``Program.resolve``:
 
 * **fast stream** — when no ``MaybeEvict`` can fire at this env (no
-  memory limit, or the replayed peak fits under it), the hot loop is
-  exactly: gather input registers, bind the primitive, store outputs,
-  null dead registers.  All sizes/params were resolved once per env and
-  the call's complete ``MemoryStats`` was precomputed by the resolve
-  replay — per-op dispatch overhead collapses to list indexing.
+  memory limit, or the replayed peak fits under it), each op-group run
+  of the stream (``Program.groups``, ``Program.segments``) is one
+  ``jax.jit`` executable: the per-op loop below traced once per env, a
+  rolled loop as one ``lax.scan`` of its body.  XLA fuses the run's ops,
+  and the call is one dispatch per run.  JAX caches the executables per
+  env (a static argument), apart from ``Program.resolve``'s cache.  All
+  sizes/params were resolved once per env and the call's complete
+  ``MemoryStats`` was precomputed by the resolve replay.  Outputs equal
+  the reference ``PlanInterpreter``'s within f32 round-off, not bitwise
+  (fusion reorders and fuses the arithmetic).
 * **dynamic stream** — under real memory pressure the full instruction
-  stream runs: ``MaybeEvict`` triggers the runtime remat policy at the
-  op boundaries the lowering marked, ``Regen`` rematerializes evicted
-  registers through reload or the candidate's lowered sub-program, and
-  frees honor regeneration holds.  Outputs are bitwise-identical to the
-  reference ``PlanInterpreter``; eviction counters can differ only when
-  victim scores tie exactly after remat churn (the interpreter's
-  storage-dict iteration order mutates on reinsertion, the VM's
-  candidate order is static).
+  stream runs one bind per op: ``MaybeEvict`` triggers the runtime remat
+  policy at the op boundaries the lowering marked, ``Regen``
+  rematerializes evicted registers through reload or the candidate's
+  lowered sub-program, and frees honor regeneration holds.  Outputs are
+  bitwise-identical to the reference ``PlanInterpreter``; eviction
+  counters can differ only when victim scores tie exactly after remat
+  churn (the interpreter's storage-dict iteration order mutates on
+  reinsertion, the VM's candidate order is static).
+
+A call with an armed kernel fault runs the fast stream per op too
+(``_run_fast_faulted``: a fault probe before every bind, a rolled loop's
+body per trip), bitwise equal to the interpreter like the dynamic
+stream.
 """
 from __future__ import annotations
 
 import contextlib
+import re
 import time
 from typing import Any, Dict, List, Optional, Sequence, Tuple
 
+import jax
 import jax.numpy as jnp
 import numpy as np
 from jax import lax
@@ -68,6 +80,11 @@ class ProgramVM:
         # The fast stream is never instrumented — its occupancy curve is
         # exactly reconstructible off the hot path (obs.timeline)
         self.timeline_hook = None
+        # per op group: its run's jitted executable (built on first use;
+        # JAX keeps one compiled program per env under it)
+        self._segment_fns: List[Any] = [None] * len(program.groups)
+        self._consts = tuple(i for i in program.fast_instructions
+                             if i.op == OP_BIND_ARG and i.index < 0)
 
     # knobs live on the lowered artifact (they shaped the emission)
     @property
@@ -144,13 +161,14 @@ class ProgramVM:
     # ------------------------------------------------------------- loops --
     def _exec_loop(self, info, rl, ins: Sequence[Any],
                    env: Dict[str, int]) -> List[Any]:
-        """Run one rolled loop: the lowered body sub-Program per iteration
-        with registers rebound (carries from the previous iteration's
-        output registers, ``xs`` slices by index).
+        """Run one rolled loop per op (the dynamic and faulted streams): the
+        lowered body sub-Program per iteration with registers rebound
+        (carries from the previous iteration's output registers, ``xs``
+        slices by index).
 
         Pure execution — memory accounting happens through the shared
         ``LoopPlanInfo.account`` engine (dynamic path) or the resolve-time
-        stats replay (fast path).  The body runs the same nodes in the
+        stats replay (faulted path).  The body runs the same nodes in the
         same order with the same refined params as the reference
         interpreter's op-by-op loop, so outputs are bitwise-identical."""
         body, lp = info.body, info.lp
@@ -184,25 +202,95 @@ class ProgramVM:
                        for v in lp.y_out]
         return carries + stacked
 
+    def _scan_loop(self, info, rl, ins: Sequence[Any],
+                   env: Dict[str, int]) -> List[Any]:
+        """``_exec_loop`` inside a traced op-group run: the body, traced
+        once, as a ``lax.scan`` of ``rl.trip`` iterations."""
+        body, lp = info.body, info.lp
+        bprog = info.body_program
+        nc, nk = body.num_consts, body.num_carry
+        consts_args = list(ins[:nc])
+        xs = [x if lp.x_used[j] else None
+              for j, x in enumerate(ins[nc + nk:])]
+        out_regs = bprog.out_regs          # carries then ys
+
+        def step(carries, x):
+            storage: List[Any] = [None] * bprog.n_regs
+            self._run_range(storage, consts_args + list(carries) + list(x),
+                            rl.rbody, bprog.fast_instructions)
+            return ([storage[r] for r in out_regs[:nk]],
+                    [storage[r] for r in out_regs[nk:]])
+
+        carries, ys = lax.scan(step, list(ins[nc:nc + nk]), xs,
+                               length=rl.trip)
+        return list(carries) + list(ys)
+
     # ------------------------------------------------------------ fast path
     def _run_fast(self, flat_args: Sequence[Any], resolved: ResolvedProgram,
                   prof: Any = None) -> Tuple[List[Any], MemoryStats]:
-        """The fast stream: one run over the whole instruction list, or,
-        in a profiled call, one run per op group inside its span."""
+        """The fast stream: one jitted executable per op-group run, in a
+        profiled call each inside its span."""
         prog = self.program
         storage: List[Any] = [None] * prog.n_regs
         if prof is None:
-            self._run_range(storage, flat_args, resolved,
-                            prog.fast_instructions)
+            for gi in range(len(prog.groups)):
+                self._run_segment(storage, flat_args, resolved, gi)
         else:
             prof.run_groups(self, storage, flat_args, resolved)
         outputs = [storage[r] for r in prog.out_regs]
         return outputs, prog.stats_for(resolved)
 
+    def _run_segment(self, storage: List[Any], flat_args: Sequence[Any],
+                     resolved: ResolvedProgram, gi: int) -> None:
+        """Op group ``gi``'s run: its BindArgs on the host, then one call of
+        its executable at this env (traced and compiled on the env's first
+        call), its live outputs stored and what it freed nulled."""
+        prog = self.program
+        g, seg = prog.groups[gi], prog.segments[gi]
+        self._run_range(storage, flat_args, resolved,
+                        prog.fast_instructions[g.lo:seg.lo])
+        if seg.lo == g.hi:
+            return
+        fn = self._segment_fns[gi]
+        if fn is None:
+            fn = self._segment_fns[gi] = self._segment_fn(gi)
+        outs = fn(resolved.env_key, *[storage[r] for r in seg.in_regs])
+        for r, out in zip(seg.out_regs, outs):
+            storage[r] = out
+        for r in seg.frees:
+            storage[r] = None
+
+    def _segment_fn(self, gi: int) -> Any:
+        """Op group ``gi``'s run as a ``jax.jit`` function of the env key
+        (static: JAX keeps one executable per env) and its input
+        registers, donating the dead ones ``Segment.donate`` names."""
+        prog = self.program
+        g, seg = prog.groups[gi], prog.segments[gi]
+        insts = prog.fast_instructions[seg.lo:g.hi]
+
+        def run(env_key, *ins):
+            resolved = prog.resolve(dict(env_key), self._size_cache,
+                                    self._params_cache)
+            storage: List[Any] = [None] * prog.n_regs
+            self._run_range(storage, (), resolved, self._consts)
+            for r, x in zip(seg.in_regs, ins):
+                storage[r] = x
+            self._run_range(storage, (), resolved, insts, traced=True)
+            return [storage[r] for r in seg.out_regs]
+
+        # the executable's name in the device's trace
+        run.__name__ = run.__qualname__ = "vm_" + re.sub(
+            r"\W", "_", g.label or "ops")
+        return jax.jit(run, static_argnums=0,
+                       donate_argnums=tuple(1 + k for k in seg.donate))
+
     def _run_range(self, storage: List[Any], flat_args: Sequence[Any],
-                   resolved: ResolvedProgram, insts: Sequence[Any]) -> None:
+                   resolved: ResolvedProgram, insts: Sequence[Any],
+                   traced: bool = False) -> None:
         """The fast stream's loop body (its only copy) over ``insts``: gather
-        input registers, bind, store outputs, null dead registers."""
+        input registers, bind, store outputs, null dead registers.  Eager
+        per op (the faulted stream, a run's BindArgs), or ``traced`` inside
+        an op-group executable, where a rolled loop is a ``lax.scan``."""
         prog = self.program
         params = resolved.params
         for inst in insts:
@@ -227,7 +315,8 @@ class ProgramVM:
             elif op == OP_FREE_SLOT or op == OP_DONATE:
                 storage[inst.reg] = None
             elif op == OP_LOOP:
-                outs = self._exec_loop(
+                run_loop = self._scan_loop if traced else self._exec_loop
+                outs = run_loop(
                     prog.loops[inst.lidx], resolved.loops[inst.lidx],
                     [storage[r] for r in inst.in_regs], resolved.env)
                 for oi, r in inst.store:

@@ -20,6 +20,7 @@ structure:
 """
 from __future__ import annotations
 
+from collections import Counter
 from typing import Any, Dict, List, Optional, Tuple
 
 from ..ir.graph import Value
@@ -27,9 +28,10 @@ from ..ir.loop import loop_body_of
 from ..ir.trace import _contains_symbolic
 from ..remat.export import export_regen_programs
 from ..remat.planner import ExecutionPlan
-from .program import (OP_COMPUTE, OP_LOOP, BindArg, BindDim, Compute, Donate,
-                      FreeSlot, Loop, LoopInfo, MaybeEvict, OpGroup, Program,
-                      Regen, Return)
+from .program import (OP_BIND_ARG, OP_COMPUTE, OP_DONATE, OP_FREE_SLOT,
+                      OP_LOOP, BindArg, BindDim, Compute, Donate, FreeSlot,
+                      Loop, LoopInfo, MaybeEvict, OpGroup, Program, Regen,
+                      Return, Segment)
 
 
 def op_groups(fast: List[Any], loops: List[LoopInfo]) -> List[OpGroup]:
@@ -55,6 +57,56 @@ def op_groups(fast: List[Any], loops: List[LoopInfo]) -> List[OpGroup]:
     ends = [lo for lo, _, _ in starts[1:]] + [len(fast)]
     return [OpGroup(label, lo, hi, loop)
             for (lo, label, loop), hi in zip(starts, ends)]
+
+
+def op_segments(fast: List[Any], groups: List[OpGroup],
+                loops: List[LoopInfo]) -> List[Segment]:
+    """Per op-group run, its :class:`Segment`."""
+    consts = {inst.reg for inst in fast
+              if inst.op == OP_BIND_ARG and inst.index < 0}
+    # the value each Compute or Loop writes, by register
+    made: Dict[int, Value] = {}
+    for inst in fast:
+        if inst.op == OP_COMPUTE:
+            outvals = inst.node.outvals
+        elif inst.op == OP_LOOP:
+            outvals = loops[inst.lidx].node.outvals
+        else:
+            continue
+        for oi, r in inst.store:
+            made[r] = outvals[oi]
+    out: List[Segment] = []
+    for g in groups:
+        lo = g.lo
+        while lo < g.hi and fast[lo].op == OP_BIND_ARG:
+            lo += 1
+        run = fast[lo:g.hi]
+        if not any(i.op in (OP_COMPUTE, OP_LOOP) for i in run):
+            lo, run = g.hi, []       # the host's per-op loop does it all
+        frees = tuple(i.reg for i in run
+                      if i.op in (OP_FREE_SLOT, OP_DONATE))
+        written: Dict[int, None] = {}
+        reads: Dict[int, None] = {}        # first-read order
+        for i in run:
+            if i.op in (OP_COMPUTE, OP_LOOP):
+                reads.update((r, None) for r in i.in_regs
+                             if r not in written and r not in consts)
+                written.update((r, None) for _oi, r in i.store)
+        in_regs = tuple(reads)
+        dead = set(frees)
+        out_regs = tuple(r for r in written if r not in dead)
+        # an output of the same shape and dtype takes a donated buffer
+        # over at every env; XLA would drop (and warn of) any other
+        takers = Counter((made[r].dims, made[r].dtype) for r in out_regs)
+        donate = []
+        for k, r in enumerate(in_regs):
+            key = (made[r].dims, made[r].dtype) if r in made else None
+            if r in dead and takers[key] > 0:
+                takers[key] -= 1
+                donate.append(k)
+        out.append(Segment(lo=lo, in_regs=in_regs, out_regs=out_regs,
+                           frees=frees, donate=tuple(donate)))
+    return out
 
 
 def lower_plan(plan: ExecutionPlan, *,
@@ -230,6 +282,7 @@ def lower_plan(plan: ExecutionPlan, *,
                 dep_lists[name].append(r)
         bound_dep_regs = {name: tuple(rs) for name, rs in dep_lists.items()}
 
+    groups = op_groups(fast, loops)
     return Program(plan=plan, graph=g, n_regs=len(vid_of), reg_of=reg_of,
                    vid_of=vid_of, nbytes_exprs=nbytes_exprs,
                    instructions=instructions, fast_instructions=fast,
@@ -240,4 +293,4 @@ def lower_plan(plan: ExecutionPlan, *,
                    memory_limit=memory_limit, donate_inputs=donate_inputs,
                    count_inputs=count_inputs, loops=loops,
                    bound_dep_regs=bound_dep_regs,
-                   groups=op_groups(fast, loops))
+                   groups=groups, segments=op_segments(fast, groups, loops))

@@ -200,6 +200,27 @@ class OpGroup(NamedTuple):
     loop: bool
 
 
+class Segment(NamedTuple):
+    """An op-group run of the fast stream as one function of its
+    registers, which the VM compiles once per resolved env (``jax.jit``).
+
+    The run's leading BindArgs (``[group.lo, lo)``) place caller inputs
+    and trace constants on the host; ``[lo, group.hi)`` is what the
+    executable replays (``lo == group.hi``: nothing to replay).
+    ``in_regs`` are the registers it reads before it writes them, trace
+    constants excepted (the replay closes over those); ``out_regs`` the
+    registers it writes that are still live at its end; ``frees`` the
+    registers its FreeSlot/Donate instructions null.  ``donate`` are the
+    positions in ``in_regs`` the executable consumes: registers the VM
+    produced whose last use lies in the run, each matched to an output
+    of the same symbolic shape and dtype that takes its buffer over."""
+    lo: int
+    in_regs: Tuple[int, ...]
+    out_regs: Tuple[int, ...]
+    frees: Tuple[int, ...]
+    donate: Tuple[int, ...]
+
+
 @dataclass
 class LoopInfo:
     """Compile-time half of one rolled loop inside a Program."""
@@ -279,6 +300,9 @@ class ResolvedProgram:
     # (``Program.groups``), filled on a profiled call's first use of this
     # env (``obs.profile``)
     group_plan_bytes: Optional[List[int]] = None
+    # the declared env as a hashable key: the static argument under which
+    # JAX caches each op-group executable for this env
+    env_key: Tuple[Tuple[str, int], ...] = ()
 
 
 @dataclass
@@ -312,8 +336,10 @@ class Program:
     # by the BindDim that publishes the measured value)
     bound_dep_regs: Dict[str, Tuple[int, ...]] = field(default_factory=dict)
     # the fast stream cut into op-group runs (a profiled call opens one
-    # span per run)
+    # span per run), and per run its Segment: the fast stream runs one
+    # jitted executable per run
     groups: List[OpGroup] = field(default_factory=list)
+    segments: List[Segment] = field(default_factory=list)
 
     def __post_init__(self):
         self._resolve_cache: Dict[Tuple, ResolvedProgram] = {}
@@ -443,7 +469,8 @@ class Program:
         out = ResolvedProgram(env=dict(env), nbytes=nbytes,
                               ensure_bytes=ensure, params=params,
                               regen_flops=regen_flops, arena=arena,
-                              value_offsets=offsets or {}, loops=rloops)
+                              value_offsets=offsets or {}, loops=rloops,
+                              env_key=key[1:])
         out.stats_template, out.peak_bytes = self._replay_stats(
             nbytes, arena, rloops)
         # bound programs measure sizes mid-call, so the precomputed stats

@@ -9,14 +9,17 @@ of a ``DynamicShapeFunction`` records, on the calling thread::
       dsf.solve_env                           env solve + declared ranges
       vm.resolve                              Program.resolve for the env
       vm.stream                               the instruction stream
-        <op-group label> (seq, hbm_bytes, plan_bytes)   one per run
-          vm.loop (trips)                     a rolled loop's run
+        <op-group label> (seq, hbm_bytes, plan_bytes)   one per run:
+                                              its executable's dispatch
+          vm.loop (trips)                     a run holding a rolled loop
       python.gc (generation)                  a collection inside the call
 
 and pushes one :class:`.telemetry.CallRecord` with its phase times and
 counters to :data:`.telemetry.PROFILE_SINK`.  Op-group runs exist on the
-fast stream (``Program.groups``); the dynamic (eviction) stream is one
-``vm.stream`` span.
+fast stream (``Program.groups``), each one jitted executable
+(``Program.segments``; ``segments`` and ``fused_binds`` count them and
+the binds inside); the dynamic (eviction) and faulted streams are one
+``vm.stream`` span, per op.
 
 At the end of every run the device's ``bytes_in_use`` is read and set
 beside the plan's occupancy at that instruction (the exact replay of
@@ -65,13 +68,15 @@ class CallProfile:
     """Phase times and counters of one profiled call (see module doc)."""
 
     __slots__ = ("seq", "solve_ns", "resolve_ns", "stream_ns", "call_ns",
-                 "binds", "gc_ns", "gc_collections", "hbm_over_plan_bytes",
-                 "report", "program", "_gc_span", "_gc_t0", "on_gc")
+                 "binds", "segments", "fused_binds", "gc_ns",
+                 "gc_collections", "hbm_over_plan_bytes", "report",
+                 "program", "_gc_span", "_gc_t0", "on_gc")
 
     def __init__(self):
         self.seq = next(_SEQ)
         self.solve_ns = self.resolve_ns = self.stream_ns = self.call_ns = 0
-        self.binds = self.gc_ns = self.gc_collections = 0
+        self.binds = self.segments = self.fused_binds = 0
+        self.gc_ns = self.gc_collections = 0
         self.hbm_over_plan_bytes = 0
         # set by the dispatch that served the call
         self.report = self.program = None
@@ -82,7 +87,8 @@ class CallProfile:
     def counters(self) -> Dict[str, int]:
         return dict(solve_ns=self.solve_ns, resolve_ns=self.resolve_ns,
                     stream_ns=self.stream_ns, call_ns=self.call_ns,
-                    binds=self.binds, gc_ns=self.gc_ns,
+                    binds=self.binds, segments=self.segments,
+                    fused_binds=self.fused_binds, gc_ns=self.gc_ns,
                     gc_collections=self.gc_collections,
                     hbm_over_plan_bytes=self.hbm_over_plan_bytes)
 
@@ -101,8 +107,9 @@ class CallProfile:
 
     def run_groups(self, vm: Any, storage: List[Any],
                    flat_args: Sequence[Any], resolved: Any) -> None:
-        """The fast stream, one ``vm._run_range`` per op-group run, each
-        inside its span, with the device's bytes in use read at its end."""
+        """The fast stream, one op-group run (its executable) at a time,
+        each inside its span, with the device's bytes in use read at its
+        end."""
         prog = vm.program
         insts = prog.fast_instructions
         planned = resolved.group_plan_bytes
@@ -112,23 +119,28 @@ class CallProfile:
                 points[g.hi - 1].device_used for g in prog.groups]
         in_use = _in_use_reader()
         worst = None
-        for g, plan_bytes in zip(prog.groups, planned):
+        for gi, (g, seg, plan_bytes) in enumerate(
+                zip(prog.groups, prog.segments, planned)):
             run = insts[g.lo:g.hi]
             with TraceAnnotation(g.label or "vm.ops", seq=self.seq) as span:
                 if g.loop:
                     lidx = next(i.lidx for i in run if i.op == OP_LOOP)
                     with TraceAnnotation("vm.loop",
                                          trips=resolved.loops[lidx].trip):
-                        vm._run_range(storage, flat_args, resolved, run)
+                        vm._run_segment(storage, flat_args, resolved, gi)
                 else:
-                    vm._run_range(storage, flat_args, resolved, run)
+                    vm._run_segment(storage, flat_args, resolved, gi)
+                self.segments += seg.lo < g.hi
                 used = in_use()
                 span.set_metadata(hbm_bytes=used, plan_bytes=plan_bytes)
             if worst is None or used - plan_bytes > worst:
                 worst = used - plan_bytes
         if worst is not None:
             self.hbm_over_plan_bytes = worst
-        self.binds += _binds(prog, resolved)
+        binds = _binds(prog, resolved)
+        # every bind of this stream runs inside an op-group executable
+        self.binds += binds
+        self.fused_binds += binds
 
 
 def profile_call(fn: Any, args: tuple, kwargs: dict) -> Any:

@@ -46,14 +46,16 @@ class CallRecord(NamedTuple):
     # profiled calls only (obs.profile; 0 otherwise): host ns in the env
     # solve + declared-range check, Program.resolve, the instruction
     # stream, and the whole call (flatten to unflatten); primitive binds
-    # executed; Python GC pauses inside the call; the largest excess of
-    # the device's bytes in use over the plan's occupancy at an op-group
-    # boundary
+    # executed; jitted op-group runs dispatched, and the binds inside them;
+    # Python GC pauses inside the call; the largest excess of the device's
+    # bytes in use over the plan's occupancy at an op-group boundary
     solve_ns: int = 0
     resolve_ns: int = 0
     stream_ns: int = 0
     call_ns: int = 0
     binds: int = 0
+    segments: int = 0
+    fused_binds: int = 0
     gc_ns: int = 0
     gc_collections: int = 0
     hbm_over_plan_bytes: int = 0

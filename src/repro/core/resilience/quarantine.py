@@ -58,14 +58,15 @@ class BreakerConfig:
 
 
 class _Entry:
-    __slots__ = ("state", "failures", "opens", "retry_at", "cause",
-                 "probing")
+    __slots__ = ("state", "failures", "opens", "retry_at", "backoff",
+                 "cause", "probing")
 
     def __init__(self) -> None:
         self.state = "closed"
         self.failures = 0       # consecutive failures while closed
         self.opens = 0          # consecutive open episodes (backoff exponent)
         self.retry_at = 0.0
+        self.backoff = 0.0      # the open episode's window
         self.cause: Optional[BaseException] = None
         self.probing = False    # a half-open probe is in flight
 
@@ -131,8 +132,19 @@ class CircuitBreaker:
             e.opens += 1
             e.failures = 0
             e.state = "open"
+            e.backoff = backoff
             e.retry_at = self.clock() + backoff
             self._log(key, "open", backoff_s=backoff, cause=repr(exc))
+
+    def restart_backoff(self, key: BucketKey) -> None:
+        """Start an open key's window again from now: called once the
+        request whose failure opened it has been served by the fallback,
+        so a slow fallback call (its first at a new shape compiles) does
+        not spend the quarantine before the next request sees it."""
+        with self._lock:
+            e = self._entries.get(key)
+            if e is not None and e.state == "open":
+                e.retry_at = self.clock() + e.backoff
 
     def record_success(self, key: BucketKey) -> None:
         with self._lock:

@@ -6,9 +6,9 @@ itself and no other directory is set in code.  Otherwise the cache is
 part of what a later run must find again), listed in ``.gitignore``.
 
 The minimum compile time worth caching drops from JAX's default 1 s to
-0: the VM binds one primitive at a time, and each new concrete shape
-compiles every op again in well under a second, so at the default only
-whole-step ``jax.jit`` programs would ever be kept.
+0: many of the VM's programs compile in well under a second (a short
+op-group run's executable; a primitive of the per-op dynamic stream),
+and at the default each new process would compile them again.
 
 Call :func:`configure_compile_cache` before the process compiles
 anything: JAX fixes the cache directory at its first compile.

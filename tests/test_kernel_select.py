@@ -33,6 +33,7 @@ from hypothesis import given, settings
 from hypothesis import strategies as st
 
 from repro.core import optimize, symbolic_dim, symbolic_dims
+from repro.core.ir.trace import solve_checked_env
 from repro.kernels import flash_attention, masked_select, rmsnorm
 from repro.kernels.hw_model import TPU_V5E
 from repro.kernels.ref import reference_attention, reference_rmsnorm
@@ -309,8 +310,10 @@ def test_bounded_dims_path_vm_eq_interpreter():
 
 
 def test_rolled_scan_body_kernels_vm_eq_interpreter():
-    """A kernel inside a rolled scan body auto-selects eagerly at the
-    concrete per-step shape — identically under both executors."""
+    """A kernel inside a rolled scan body auto-selects at the concrete
+    per-step shape — identically under both executors: the VM's per-op
+    stream bitwise, its fused fast stream (the loop one ``lax.scan``
+    inside its run's executable) within f32 round-off."""
     def f(xs, scale):
         def body(c, x):
             h = rmsnorm(x, scale)
@@ -328,10 +331,19 @@ def test_rolled_scan_body_kernels_vm_eq_interpreter():
     for steps in (1, 5):
         xs = jnp.asarray(rng.randn(steps, 8, D), jnp.float32)
         scale = jnp.asarray(rng.randn(D), jnp.float32)
-        for a, b in zip(jax.tree_util.tree_leaves(vm(xs, scale)),
-                        jax.tree_util.tree_leaves(ref(xs, scale))):
-            assert np.array_equal(np.asarray(a), np.asarray(b)), steps
-        assert _stats(vm) == _stats(ref), steps
+        outs_ref = jax.tree_util.tree_leaves(ref(xs, scale))
+        fused = jax.tree_util.tree_leaves(vm(xs, scale))
+        stats_fused = _stats(vm)
+        flat = [xs, scale]
+        prog = vm.program
+        env = solve_checked_env(prog.graph, prog.plan.shape_graph, flat)
+        per_op, _ = vm.interp._run_dynamic(flat, prog.resolve(env), env)
+        for a, o, b in zip(fused, per_op, outs_ref):
+            b = np.asarray(b)
+            assert np.array_equal(np.asarray(o), b), steps
+            np.testing.assert_allclose(np.asarray(a), b, rtol=0,
+                                       atol=1e-5 * np.max(np.abs(b)))
+        assert stats_fused == _stats(ref), steps
 
 
 # -- measured fallback ---------------------------------------------------------

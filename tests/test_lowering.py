@@ -11,6 +11,7 @@ from repro.core.executor.interpreter import PlanInterpreter
 from repro.core.executor.memory import MemoryLimitExceeded
 from repro.core.executor.vm import ProgramVM
 from repro.core.ir import trace_to_graph
+from repro.core.ir.trace import solve_checked_env
 from repro.core.lowering.program import (OP_COMPUTE, OP_MAYBE_EVICT,
                                          OP_REGEN)
 from repro.core.remat.planner import build_plan
@@ -60,6 +61,34 @@ def _assert_trees_equal(a, b):
             "executors disagree bitwise"
 
 
+def _assert_trees_close(a, b, rel=1e-5):
+    """Equal within ``rel`` of each output's largest magnitude: the fused
+    fast stream against a per-op reference (XLA fuses the ops, so the f32
+    round-off differs); integer and boolean outputs stay exact."""
+    la = tree_util.tree_leaves(a)
+    lb = tree_util.tree_leaves(b)
+    assert len(la) == len(lb)
+    for x, y in zip(la, lb):
+        x, y = np.asarray(x), np.asarray(y)
+        assert x.shape == y.shape and x.dtype == y.dtype
+        if not jnp.issubdtype(y.dtype, jnp.inexact):
+            assert np.array_equal(x, y), "executors disagree"
+            continue
+        x, y = x.astype(np.float64), y.astype(np.float64)
+        scale = np.max(np.abs(y), initial=0.0)
+        assert np.max(np.abs(x - y), initial=0.0) <= rel * scale, \
+            "executors disagree beyond round-off"
+
+
+def _run_per_op(vm, flat):
+    """``vm``'s per-op stream at ``flat``'s env: the dynamic stream, which
+    a memory cap takes; with no cap it runs the fast stream's ops one bind
+    at a time.  Returns (outputs, stats)."""
+    prog = vm.program
+    env = solve_checked_env(prog.graph, prog.plan.shape_graph, flat)
+    return vm._run_dynamic(flat, prog.resolve(env), env)
+
+
 # -- the differential harness: every bench arch, both executors ---------------
 
 BENCH_ARCHS = ["llama2_1b", "gemma_2b", "granite_8b", "musicgen_medium"]
@@ -68,8 +97,9 @@ PROBE_ENVS = [{"b": 1, "s": 16}, {"b": 2, "s": 40}, {"b": 3, "s": 96}]
 
 @pytest.mark.parametrize("arch", BENCH_ARCHS)
 def test_differential_vm_vs_reference_on_bench_arch(arch):
-    """VM and reference interpreter agree bitwise on every bench arch at
-    >=3 probe envs, and the VM's peak bytes never exceed the reference's."""
+    """The VM's per-op stream and the reference interpreter agree bitwise on
+    every bench arch at >=3 probe envs, the fused fast stream within
+    round-off, and the VM's peak bytes never exceed the reference's."""
     from benchmarks.memplan_bench import _step_and_specs, concretize_spec
 
     r = _step_and_specs(arch)
@@ -84,8 +114,11 @@ def test_differential_vm_vs_reference_on_bench_arch(arch):
     for env in PROBE_ENVS:
         flat = [concretize_spec(s, env, rng) for s in flat_specs]
         outs_vm, rep_vm = fn.interp.run(flat)
+        outs_op, stats_op = _run_per_op(fn.interp, flat)
         outs_ref, rep_ref = ref.run(flat)
-        _assert_trees_equal(outs_vm, outs_ref)
+        _assert_trees_equal(outs_op, outs_ref)
+        _assert_trees_close(outs_vm, outs_ref)
+        assert stats_op.device_peak == rep_ref.stats.device_peak
         assert rep_vm.env == env and rep_ref.env == env
         assert rep_vm.stats.device_peak <= rep_ref.stats.device_peak
         # the fast path precomputes the whole stats template — it must
@@ -207,7 +240,10 @@ class TestVMExecution:
                         jnp.int32)
         ov = vm(params, t, t)
         orf = ref(params, t, t)
-        _assert_trees_equal(ov, orf)
+        flat = tree_util.tree_leaves(((params, t, t), {}))
+        op, _ = _run_per_op(vm.interp, flat)
+        _assert_trees_equal(op, tree_util.tree_leaves(orf))
+        _assert_trees_close(ov, orf)
         assert vm.last_report.stats.device_peak \
             == ref.last_report.stats.device_peak
         assert vm.last_report.stats.donated_reuses \

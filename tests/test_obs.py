@@ -415,13 +415,13 @@ class TestProfiledCall:
     def test_gc_callbacks_balance_and_count(self, chain_fn, monkeypatch,
                                             tmp_path):
         from repro.core.executor.vm import ProgramVM
-        run_range = ProgramVM._run_range
+        run_segment = ProgramVM._run_segment
 
         def collecting(self, *a):
             gc.collect()
-            return run_range(self, *a)
+            return run_segment(self, *a)
 
-        monkeypatch.setattr(ProgramVM, "_run_range", collecting)
+        monkeypatch.setattr(ProgramVM, "_run_segment", collecting)
         x = np.ones((6, 4), np.float32)
         n_callbacks = len(gc.callbacks)
         with jax.profiler.trace(str(tmp_path)):

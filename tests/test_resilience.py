@@ -15,7 +15,8 @@ Contract families over :mod:`repro.core.resilience` and its wiring:
     requests never retry;
   * **chaos** — randomized fault schedules across the bench archs: no
     uncaught exception escapes, surviving requests match the fault-free
-    run bitwise, every fired fault maps to a structured event/error or a
+    run (within f32 round-off: a fault can move a request from the fused
+    fast stream to a per-op one), every fired fault maps to a structured event/error or a
     breaker transition, quarantined buckets heal after faults clear, and
     arena occupancy stays under the active plan's guaranteed bound;
   * **zero overhead disabled** — with resilience off, a call allocates
@@ -93,6 +94,25 @@ def _trees_equal(a, b):
     fa, fb = _flat(a), _flat(b)
     return len(fa) == len(fb) and all(
         np.array_equal(x, y) for x, y in zip(fa, fb))
+
+
+def _close(x, y, rel):
+    if x.shape != y.shape or x.dtype != y.dtype:
+        return False
+    if not jnp.issubdtype(y.dtype, jnp.inexact):
+        return np.array_equal(x, y)
+    x, y = x.astype(np.float64), y.astype(np.float64)
+    return np.max(np.abs(x - y), initial=0.0) <= rel * np.max(
+        np.abs(y), initial=0.0)
+
+
+def _trees_close(a, b, rel=1e-5):
+    """Equal within ``rel`` of each output's largest magnitude: a run that
+    a fault moved to another stream (per op, or another plan's fused
+    executables) against the fault-free fused run."""
+    fa, fb = _flat(a), _flat(b)
+    return len(fa) == len(fb) and all(
+        _close(x, y, rel) for x, y in zip(fa, fb))
 
 
 FAST_RETRY = RetryPolicy(max_retries=2, backoff_base_s=0.0)
@@ -209,6 +229,22 @@ class TestCircuitBreaker:
         br.record_failure(key, RuntimeError("three"))
         # growth capped at max_backoff_s
         assert br.retry_in_s(key) == pytest.approx(3.0)
+
+    def test_backoff_restarts_once_the_fallback_served(self):
+        clk = FakeClock()
+        br = CircuitBreaker(BreakerConfig(backoff_s=1.0), clock=clk)
+        key = (3,)
+        br.restart_backoff(key)                   # unknown key: no-op
+        br.record_failure(key, RuntimeError("boom"))
+        clk.t = 0.8                               # a slow fallback call
+        br.restart_backoff(key)
+        assert br.retry_in_s(key) == pytest.approx(1.0)
+        clk.t = 1.5
+        assert not br.allow(key)                  # the window runs anew
+        clk.t = 1.8
+        assert br.allow(key) and br.state(key) == "half-open"
+        br.restart_backoff(key)                   # only an open key moves
+        assert br.state(key) == "half-open"
 
     def test_failure_threshold(self):
         br = CircuitBreaker(BreakerConfig(failure_threshold=3),
@@ -457,7 +493,7 @@ def test_chaos_schedule_no_crash_and_bitwise_survivors(
         chaos_arch_fn, arch, seed):
     """The acceptance chaos property, per (arch, seed): a randomized
     fault schedule crashes nothing, surviving requests match the
-    fault-free run bitwise, every fired fault maps to a structured
+    fault-free run within round-off, every fired fault maps to a structured
     event/error or breaker transition, quarantined buckets heal after
     the schedule clears, and arena occupancy respects the active bound.
     """
@@ -485,8 +521,8 @@ def test_chaos_schedule_no_crash_and_bitwise_survivors(
             except RequestFailed as e:
                 failures.append((i, e))     # structured: fine
                 continue
-            # survivor: bitwise-identical to the fault-free run
-            assert _trees_equal(out, refs[k]), \
+            # survivor: the fault-free run's outputs, within round-off
+            assert _trees_close(out, refs[k]), \
                 f"{arch} seed {seed} call {i}: outputs diverged"
             bound = fn.last_arena_bound
             if bound is not None:
@@ -513,7 +549,7 @@ def test_chaos_schedule_no_crash_and_bitwise_survivors(
         for env in CHAOS_ENVS:
             k = tuple(sorted(env.items()))
             out = fn(*calls[k])
-            assert _trees_equal(out, refs[k])
+            assert _trees_close(out, refs[k])
     assert table.quarantined() == [], \
         f"{arch} seed {seed}: buckets still quarantined after recovery"
     for env in CHAOS_ENVS:
